@@ -155,6 +155,9 @@ def main() -> None:
                     help="also write the emitted rows + run metadata as JSON "
                          "(the CI perf-trajectory artifact)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     print("name,us_per_call,derived")
     failures = 0

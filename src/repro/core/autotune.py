@@ -471,11 +471,18 @@ def autotune_result(
                    bits=bits, grid_order=pl.grid_order, hw=hw,
                    fold_batch=pl.fold_batch).t_overlapped
 
-    ranked = sorted(plans, key=score)
     # Up to max_measure survivors, always including the default: when the
     # model already ranks the default on top, the remaining slots go to
     # challengers instead of shrinking the field to a self-comparison.
-    survivors = ranked[:max(max_measure, 1)]
+    # On a chip, a plan its compiler refuses is skipped, never timed as a
+    # loser (tiling.plan_compiles; checked in rank order, so only plans
+    # that would be timed are compiled).
+    survivors = []
+    for pl in sorted(plans, key=score):
+        if len(survivors) >= max(max_measure, 1):
+            break
+        if pl == dflt or tiling.plan_compiles(p, pl, batch=batch, bits=bits):
+            survivors.append(pl)
     if dflt not in survivors:
         survivors = survivors[:max(max_measure - 1, 1)] + [dflt]
 

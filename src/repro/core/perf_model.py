@@ -47,7 +47,9 @@ class HW:
     peak_flops_int8: float = 394e12
     hbm_bw: float = 819e9
     ici_bw_per_link: float = 50e9
-    vmem_bytes: int = 16 * 2**20
+    # Scoped VMEM the kernels ask Mosaic for (kernels/mm2im_pallas.py
+    # VMEM_LIMIT); a v5e core has 128 MiB.  Plans budget 3/4 of it.
+    vmem_bytes: int = 64 * 2**20
     mxu_dim: int = 128
 
 

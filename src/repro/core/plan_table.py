@@ -66,24 +66,15 @@ def table_dir() -> Path:
     """Directory holding the shipped ``<backend>.json`` tables.
 
     ``$REPRO_PLAN_TABLE_DIR`` wins; otherwise the packaged
-    ``repro/data/plans/`` directory.  The repo is importable both as a
-    plain source tree on ``PYTHONPATH`` and as an installed distribution,
-    so we try ``importlib.resources`` first (wheel/zip safe) and fall back
-    to the path relative to this file (namespace-package source tree).
+    ``repro/data/plans/`` directory, found relative to this file.
+    (``importlib.resources`` is no help here: ``repro.data`` is a namespace
+    package, and with the source tree on ``sys.path`` twice it resolves to
+    a multiplexed path that is not a directory.)
     """
     env = os.environ.get(TABLE_DIR_ENV)
     if env:
         return Path(env).expanduser()
-    try:
-        from importlib.resources import files
-
-        p = files("repro.data").joinpath("plans")
-        # files() may return a non-filesystem Traversable in zipped
-        # installs; all current deployments are directories, so resolve to
-        # a real Path and let the fallback cover anything else.
-        return Path(str(p))
-    except Exception:
-        return Path(__file__).resolve().parent.parent / "data" / "plans"
+    return Path(__file__).resolve().parent.parent / "data" / "plans"
 
 
 def available_backends(directory: Union[str, Path, None] = None

@@ -15,12 +15,26 @@ budget for empirical tuning.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import warnings
 from typing import List, Optional
+
+import jax
+from jax._src.pallas.mosaic.error_handling import MosaicError
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.maps import TConvProblem, rows_slab
 from repro.core.perf_model import HW, V5E, mm2im_estimate
-from repro.kernels.mm2im_pallas import plan_blocks
+from repro.kernels.mm2im_pallas import (LANE, SUBLANE, lane_block,
+                                        mm2im_vmem_bytes, plan_blocks)
 from repro.kernels.ref import crop_offsets
+
+# What the chip's compiler raises when it refuses a kernel: Pallas' Mosaic
+# lowering (unsupported primitive), Mosaic itself (layout inference, e.g.
+# "unsupported shape cast"), and XLA's TPU compiler (scoped VMEM
+# exhausted).  Anything else is a bug in the caller and propagates.
+COMPILER_REFUSALS = (pltpu.LoweringException, MosaicError,
+                     jax.errors.JaxRuntimeError)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,19 +83,20 @@ def vmem_bytes(p: TConvProblem, block_oh: int, block_oc: int,
     (``mm2im_pallas`` residency); ``'mm2im_db'`` holds only the two-slot
     slab + output scratch of the DMA pipeline (``mm2im_db_pallas``), which
     is what lets the double-buffered variant run blocks the single-buffered
-    kernel cannot fit.
+    kernel cannot fit.  Both are ``mm2im_pallas.mm2im_vmem_bytes``, the
+    model the kernel's own heuristic plans with.
 
-    ``'mm2im_ks'`` shares the whole-input residency but replaces the
-    single ``(n_slab·Iw, Ks²·boc)`` product with the per-sub-kernel dense
-    products of the segregated dataflow (each over only the slab rows its
-    taps touch) plus the residue planes — strictly smaller MatMul scratch
-    whenever the stride drops taps.
-
-    ``'mm2im_og'`` also keeps the whole input resident but stages a
-    *gathered* operand per residue class — ``(bi·Iw', Jh·Jw·Ic)`` input
-    bytes for the widest sub-kernel (one class is staged at a time) —
-    plus the S² residue planes it writes exactly once; there is no
-    ``Ks²``-wide MatMul scratch and no accumulator re-read at all.
+    ``'mm2im_ks'`` and ``'mm2im_og'`` keep the whole input resident too,
+    counted like ``mm2im_vmem_bytes``: Pallas double-buffers the input
+    block (``Iw`` padded to the sublane tile, ``Ic`` to the 128-lane
+    tile), the ``(Ks², Ic, boc)`` weight block and the output block, and
+    ``block_oc`` runs rounded up to the lane tile.  Inside the cell KS
+    holds its per-sub-kernel dense products (each over only the slab rows
+    its taps touch) plus the residue planes; OG holds a *gathered*
+    operand — ``(bi·Iw', Jh·Jw·Ic)`` for the widest sub-kernel, one class
+    staged at a time — plus the S² residue planes it writes once.  Both
+    models omit Mosaic's own scratch, so a geometry within budget may
+    still be refused: :func:`plan_compiles` is the authority on the chip.
 
     ``fold_batch=True`` multiplies the batch-concatenated residencies by
     ``batch``: the folded single-buffered kernel holds the whole
@@ -91,34 +106,31 @@ def vmem_bytes(p: TConvProblem, block_oh: int, block_oc: int,
     budget that gates ``fold_batch`` candidates in :func:`candidate_plans`.
     """
     ebytes = bits // 8
+    if method not in ("mm2im_ks", "mm2im_og"):
+        return mm2im_vmem_bytes(
+            p.ih, p.iw, p.ic, p.ks, p.oc, p.stride, p.padding,
+            block_oh=block_oh, block_oc=block_oc, in_bytes=ebytes,
+            batch=batch, fold_batch=fold_batch,
+            whole_input=method != "mm2im_db")
+    from repro.core.segregate import segregate  # local: avoid cycle
+
     bi, n_slab, _, ihp, ow_p = _geometry(p, block_oh)
     bmul = batch if fold_batch else 1
-    if method == "mm2im_db":
-        x_resident = 2 * bmul * n_slab * p.iw * p.ic * ebytes  # slab slots
-    else:
-        x_resident = bmul * ihp * p.iw * p.ic * ebytes         # whole input
+    boc = lane_block(block_oc, p.oc)
+    ic_l = -(-p.ic // LANE) * LANE
+    iw_s = -(-p.iw // SUBLANE) * SUBLANE
+    planes = bmul * block_oh * ow_p * boc * 4
+    blocks = 2 * (bmul * ihp * iw_s * ic_l * ebytes            # whole input
+                  + p.ks**2 * ic_l * boc * ebytes              # weights
+                  + planes)                                    # output
+    seg = segregate(p.ks, p.stride, p.padding)
     if method == "mm2im_ks":
-        from repro.core.segregate import segregate  # local: avoid cycle
-
-        seg = segregate(p.ks, p.stride, p.padding)
-        mm_acc = (sum(bmul * (bi + sk.jh - 1) * p.iw * sk.taps
-                      * block_oc * 4
+        scratch = sum(bmul * (bi + sk.jh - 1) * iw_s * sk.taps * boc * 4
                       for sk in seg.subkernels if sk.taps)
-                  + bmul * block_oh * ow_p * block_oc * 4)     # planes
-    elif method == "mm2im_og":
-        from repro.core.segregate import segregate  # local: avoid cycle
-
-        seg = segregate(p.ks, p.stride, p.padding)
-        iw_p = ow_p // p.stride
+    else:  # "mm2im_og": the widest class's gathered operand
         gmax = max((sk.taps for sk in seg.subkernels), default=0)
-        mm_acc = (bmul * bi * iw_p * gmax * p.ic * ebytes      # gathered op
-                  + bmul * block_oh * ow_p * block_oc * 4)     # planes
-    else:
-        mm_acc = 2 * bmul * n_slab * p.iw * p.ks**2 * block_oc * 4  # mm+acc
-    return (x_resident
-            + p.ic * p.ks**2 * block_oc * ebytes               # weight block
-            + mm_acc
-            + 2 * bmul * block_oh * ow_p * block_oc * 4)       # out blocks
+        scratch = bmul * bi * (ow_p // p.stride) * gmax * ic_l * ebytes
+    return blocks + scratch + planes
 
 
 def plan(p: TConvProblem, *, batch: int = 1, bits: int = 8, hw: HW = V5E,
@@ -147,7 +159,7 @@ def plan(p: TConvProblem, *, batch: int = 1, bits: int = 8, hw: HW = V5E,
         raise ValueError(f"block_oh={block_oh} must be a positive multiple "
                          f"of stride {s}")
     bi, n_slab, n_j, ihp, ow_p = _geometry(p, block_oh)
-    n_c = -(-p.oc // block_oc)
+    n_c = -(-p.oc // lane_block(block_oc, p.oc))
 
     if grid_order is None or grid_order == "auto":
         w_bytes = p.ic * p.ks**2 * n_c * block_oc * ebytes
@@ -165,7 +177,7 @@ def plan(p: TConvProblem, *, batch: int = 1, bits: int = 8, hw: HW = V5E,
 # instead of guessing, so it also explores both explicit grid orders and
 # every plan-capable kernel variant.
 _CAND_BI = (1, 2, 4, 8, 16, 32, 64)
-_CAND_BOC = (8, 16, 32, 64, 128, 256)
+_CAND_BOC = (128, 256, 512)  # multiples of the 128-lane tile (lane_block)
 # Fallback when the registry has not been populated yet (built-ins register
 # on `kernels.ops` import).
 _CAND_METHODS = ("mm2im", "mm2im_db")
@@ -187,6 +199,71 @@ def _registered_plan_methods() -> tuple:
     return names or _CAND_METHODS
 
 
+def plan_compiles(p: TConvProblem, plan, *, batch: int = 1,
+                  bits: int = 8) -> bool:
+    """Whether this backend's compiler accepts ``plan`` (a
+    ``registry.Plan``, or a :class:`TilePlan`) for ``p``.
+
+    On a TPU the plan's kernel is compiled by Mosaic through the
+    dispatcher's execution path, once per (family, geometry, fold) and
+    problem; the grid order only permutes the index maps and shares the
+    answer.  A refusal (:data:`COMPILER_REFUSALS`) warns once, naming the
+    family, the problem and the compiler's message; any other error
+    propagates.  Off the chip the interpreter runs every plan.
+    """
+    if jax.default_backend() != "tpu":
+        return True
+    return _compiles_on_device(plan.method or "mm2im", p, batch, bits,
+                               plan.block_oh, plan.block_oc,
+                               bool(plan.fold_batch))
+
+
+def family_compiles(method: str, p: TConvProblem, *, batch: int = 1,
+                    bits: int = 8) -> bool:
+    """Whether ``method`` compiles for ``p`` at the heuristic block
+    geometry: the cheap per-family probe that keeps a family the chip's
+    compiler refuses outright (e.g. an unsupported reshape) out of the
+    candidates with one compile instead of one per geometry."""
+    from repro.kernels.registry import Plan
+
+    block_oh, block_oc = plan_blocks(p.ih, p.iw, p.ic, p.ks, p.oc, p.stride,
+                                     p.padding, in_bytes=bits // 8,
+                                     batch=batch)
+    return plan_compiles(p, Plan(block_oh, block_oc, method=method),
+                         batch=batch, bits=bits)
+
+
+@functools.lru_cache(maxsize=None)
+def _compiles_on_device(method: str, p: TConvProblem, batch: int, bits: int,
+                        block_oh: int, block_oc: int,
+                        fold_batch: bool) -> bool:
+    import jax.numpy as jnp
+
+    from repro.core.autotune import measure_epilogue
+    from repro.core.epilogue import Epilogue
+    from repro.kernels import ops
+    from repro.kernels.registry import Plan
+
+    dtype = jnp.int8 if bits == 8 else jnp.float32
+    bias, out_scale = measure_epilogue(p, dtype)
+    plan = Plan(block_oh, block_oc, fold_batch=fold_batch)
+    fn = jax.jit(lambda x, w: ops.run_registered(
+        method, x, w, stride=p.stride, padding=p.padding,
+        epilogue=Epilogue(bias=bias, out_scale=out_scale), plan=plan))
+    try:
+        fn.lower(jax.ShapeDtypeStruct((batch, p.ih, p.iw, p.ic), dtype),
+                 jax.ShapeDtypeStruct((p.ks, p.ks, p.oc, p.ic), dtype)
+                 ).compile()
+    except COMPILER_REFUSALS as e:
+        msg = str(e).strip().splitlines()[0] if str(e).strip() else repr(e)
+        warnings.warn(
+            f"{method} does not compile for {p} (batch {batch}, {bits}-bit, "
+            f"block_oh={block_oh} block_oc={block_oc} fold={fold_batch}): "
+            f"{msg}; the autotuner skips it", RuntimeWarning, stacklevel=2)
+        return False
+    return True
+
+
 def candidate_plans(
     p: TConvProblem, *, batch: int = 1, bits: int = 8, hw: HW = V5E,
     vmem_fraction: float = 0.75,
@@ -197,10 +274,14 @@ def candidate_plans(
 
     This is the autotuner's enumeration stage (paper Alg. 1 evaluated
     per-problem instead of once): all stride-aligned output-row blocks that
-    don't overrun the output, all channel blocks up to O_c, both explicit
+    don't overrun the output, every lane-legal channel block, both explicit
     grid orders, and every plan-capable registered kernel variant
     (``methods=None`` queries the registry — see
-    :func:`_registered_plan_methods`).  Where the pipeline has fewer than
+    :func:`_registered_plan_methods`) that the backend's compiler accepts
+    at its heuristic geometry (:func:`family_compiles`).  Other
+    geometries of an accepted family can still be refused (VMEM); the
+    autotuner checks each plan it times with :func:`plan_compiles`.
+    Where the pipeline has fewer than
     two row blocks to overlap, the double-buffered variant is skipped.
     Each variant is budget-filtered under its *own* VMEM residency model,
     so 'mm2im_db' legally reaches block geometries 'mm2im' cannot hold.
@@ -215,11 +296,13 @@ def candidate_plans(
     """
     if methods is None:
         methods = _registered_plan_methods()
+    methods = tuple(m for m in methods
+                    if family_compiles(m, p, batch=batch, bits=bits))
     budget = int(hw.vmem_bytes * vmem_fraction)
     s = p.stride
     seen = set()
     out: List[TilePlan] = []
-    bocs = sorted({min(p.oc, b) for b in _CAND_BOC})
+    bocs = sorted({lane_block(b, p.oc) for b in _CAND_BOC})
     folds = (False,) if batch <= 1 else (False, True)
     for bi in _CAND_BI:
         block_oh = s * bi

@@ -76,9 +76,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, *,
                             jnp.zeros_like(outputs))
         return jax.lax.psum(outputs, axis)
 
-    from repro.compat import shard_map
-
-    sm = shard_map(
+    sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

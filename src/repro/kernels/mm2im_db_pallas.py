@@ -23,8 +23,8 @@ grid dimension becomes an in-kernel ``fori_loop``.
 
 Numerics: host staging, the MXU MatMul, the col2im residue adds and the
 PPU epilogue are *shared code* with the single-buffered kernel
-(``prepare_mm2im`` / ``matmul_slab`` / ``col2im_accumulate`` /
-``ppu_epilogue``), so both variants are **bit-identical** — the autotuner
+(``prepare_mm2im`` / ``stage_slabs`` / ``mm2im_block`` /
+``residue_outputs``), so both variants are **bit-identical** — the autotuner
 (``core/autotune.py``) is free to pick per problem on speed alone.
 
 Interpret-mode note: the async-copy/semaphore path itself runs under
@@ -41,17 +41,18 @@ import os
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.mm2im_pallas import (
-    MM2IMPrep,
-    col2im_accumulate,
+    Col2im,
     grid_semantics,
-    matmul_slab,
-    ppu_epilogue,
+    mm2im_block,
     prepare_mm2im,
+    residue_outputs,
+    slab_out_shape,
+    stage_slabs,
+    unstage_output,
 )
 
 _N_SLOTS = 2  # two-slot scratch: fill slot A while computing from slot B
@@ -60,10 +61,8 @@ _N_SLOTS = 2  # two-slot scratch: fill slot A while computing from slot B
 def _mm2im_db_kernel(
     x_hbm_ref, w_ref, b_ref, s_ref, o_hbm_ref,   # operands (x/o in ANY/HBM)
     slab_ref, outb_ref, *sems,                   # two-slot scratch (+ sems)
-    batch_axis: int, n_j: int, block_oh: int, oc_p: int, async_copies: bool,
-    s: int, ks: int, ct: int, cl: int, bi: int, n_slab: int, iw: int,
-    ow: int, ow_p: int, boc: int, delta: int, acc_dtype, out_dtype,
-    activation: str, out_scale, per_channel: bool,
+    batch_axis: int, n_j: int, async_copies: bool, sched: Col2im, boc: int,
+    **epilogue,
 ):
     """One grid cell: ALL row blocks of one (batch, oc-block) pair.
 
@@ -77,23 +76,26 @@ def _mm2im_db_kernel(
 
     The sync fallback replaces the four DMA arrows with direct VMEM
     reads/writes of the same slices — identical block math either way.
+    Folded, the batch axis has one element and every slab carries the
+    whole batch (``stage_slabs``).
     """
     bsel = pl.program_id(batch_axis)
     csel = pl.program_id(1 - batch_axis)
+    rows = sched.n_rows * sched.pitch
+    lanes = sched.s * boc
     if async_copies:
         in_sem, out_sem = sems
 
     def in_dma(slot, j):
         return pltpu.make_async_copy(
-            x_hbm_ref.at[bsel, pl.dslice(j * bi, n_slab)],
-            slab_ref.at[pl.dslice(slot * n_slab, n_slab)],
-            in_sem.at[slot])
+            x_hbm_ref.at[bsel, pl.ds(j * sched.block_rows, rows)],
+            slab_ref.at[slot], in_sem.at[slot])
 
     def out_dma(slot, j):
         return pltpu.make_async_copy(
-            outb_ref.at[pl.dslice(slot * block_oh, block_oh)],
-            o_hbm_ref.at[bsel, pl.dslice(j * block_oh, block_oh), :,
-                         pl.dslice(csel * boc, boc)],
+            outb_ref.at[slot],
+            o_hbm_ref.at[bsel, pl.ds(j * sched.bi, sched.bi), :, :,
+                         pl.ds(csel * lanes, lanes)],
             out_sem.at[slot])
 
     if async_copies:
@@ -112,106 +114,18 @@ def _mm2im_db_kernel(
             def _retire():
                 out_dma(slot, j - _N_SLOTS).wait()
         else:
-            slab_ref[pl.dslice(slot * n_slab, n_slab)] = (
-                x_hbm_ref[bsel, pl.dslice(j * bi, n_slab)])
+            slab_ref[slot] = x_hbm_ref[bsel,
+                                       pl.ds(j * sched.block_rows, rows)]
 
-        slab = slab_ref[pl.dslice(slot * n_slab, n_slab)]
-        mm5 = matmul_slab(slab, w_ref[...], n_slab=n_slab, iw=iw, ks=ks,
-                          boc=boc, acc_dtype=acc_dtype)
-        out = col2im_accumulate(
-            mm5, s=s, ks=ks, ct=ct, cl=cl, bi=bi, n_slab=n_slab, iw=iw,
-            ow=ow, ow_p=ow_p, boc=boc, delta=delta, acc_dtype=acc_dtype)
-        out = ppu_epilogue(
-            out, b_ref[...], s_ref[...], acc_dtype=acc_dtype,
-            activation=activation, out_scale=out_scale,
-            per_channel=per_channel, out_dtype=out_dtype)
-
-        if async_copies:
-            outb_ref[pl.dslice(slot * block_oh, block_oh)] = out
-            out_dma(slot, j).start()
-        else:
-            o_hbm_ref[bsel, pl.dslice(j * block_oh, block_oh), :,
-                      pl.dslice(csel * boc, boc)] = out
-        return 0
-
-    jax.lax.fori_loop(0, n_j, body, 0)
-
-    if async_copies:
-        # Drain: the last one or two output DMAs are still in flight.
-        if n_j >= _N_SLOTS:
-            out_dma((n_j - 2) % _N_SLOTS, n_j - 2).wait()
-        out_dma((n_j - 1) % _N_SLOTS, n_j - 1).wait()
-
-
-def _mm2im_db_folded_kernel(
-    x_hbm_ref, w_ref, b_ref, s_ref, o_hbm_ref,   # operands (x/o in ANY/HBM)
-    slab_ref, outb_ref, *sems,                   # two-slot scratch (+ sems)
-    b: int, n_j: int, block_oh: int, oc_p: int, async_copies: bool,
-    s: int, ks: int, ct: int, cl: int, bi: int, n_slab: int, iw: int,
-    ow: int, ow_p: int, boc: int, delta: int, acc_dtype, out_dtype,
-    activation: str, out_scale, per_channel: bool,
-):
-    """Batch-folded grid cell: ALL row blocks of one oc-block, all batches.
-
-    Same two-slot pipeline as :func:`_mm2im_db_kernel`, but each in-DMA
-    fetches the *batch-concatenated* slab ``x[:, j*bi : j*bi+n_slab]``
-    (shape ``(B, n_slab, Iw, Ic)``) into one slot, the MatMul folds it
-    into a single ``(B·n_slab·Iw, Ic)`` MXU product, and col2im + the PPU
-    epilogue run per batch element over views of the folded product (the
-    unfolded reduction order, so bit-identical — docs/DESIGN.md §2.5).
-    The grid drops both the batch axis and the row-block axis:
-    ``grid = (oc-blocks,)``.
-    """
-    csel = pl.program_id(0)
-    if async_copies:
-        in_sem, out_sem = sems
-
-    def in_dma(slot, j):
-        return pltpu.make_async_copy(
-            x_hbm_ref.at[:, pl.dslice(j * bi, n_slab)],
-            slab_ref.at[slot],
-            in_sem.at[slot])
-
-    def out_dma(slot, j):
-        return pltpu.make_async_copy(
-            outb_ref.at[slot],
-            o_hbm_ref.at[:, pl.dslice(j * block_oh, block_oh), :,
-                         pl.dslice(csel * boc, boc)],
-            out_sem.at[slot])
-
-    if async_copies:
-        in_dma(0, 0).start()  # pipeline warm-up: first folded slab in flight
-
-    def body(j, _):
-        slot = jax.lax.rem(j, _N_SLOTS)
-        if async_copies:
-            @pl.when(j + 1 < n_j)
-            def _prefetch():
-                in_dma(jax.lax.rem(j + 1, _N_SLOTS), j + 1).start()
-            in_dma(slot, j).wait()
-            @pl.when(j >= _N_SLOTS)
-            def _retire():
-                out_dma(slot, j - _N_SLOTS).wait()
-        else:
-            slab_ref[slot] = x_hbm_ref[:, pl.dslice(j * bi, n_slab)]
-
-        slab = slab_ref[slot]  # (B, n_slab, iw, ic)
-        mm5 = matmul_slab(slab, w_ref[...], n_slab=b * n_slab, iw=iw, ks=ks,
-                          boc=boc, acc_dtype=acc_dtype)
-        for e in range(b):
-            out = col2im_accumulate(
-                mm5[e * n_slab:(e + 1) * n_slab], s=s, ks=ks, ct=ct, cl=cl,
-                bi=bi, n_slab=n_slab, iw=iw, ow=ow, ow_p=ow_p, boc=boc,
-                delta=delta, acc_dtype=acc_dtype)
-            out = ppu_epilogue(
-                out, b_ref[...], s_ref[...], acc_dtype=acc_dtype,
-                activation=activation, out_scale=out_scale,
-                per_channel=per_channel, out_dtype=out_dtype)
+        acc = mm2im_block(slab_ref.at[slot], 0, w_ref, sched,
+                          acc_dtype=epilogue["acc_dtype"])
+        for a, b, out in residue_outputs(acc, sched, b_ref[...], s_ref[...],
+                                         boc=boc, **epilogue):
             if async_copies:
-                outb_ref[slot, e] = out
+                outb_ref[slot, :, a, :, pl.ds(b * boc, boc)] = out
             else:
-                o_hbm_ref[e, pl.dslice(j * block_oh, block_oh), :,
-                          pl.dslice(csel * boc, boc)] = out
+                o_hbm_ref[bsel, pl.ds(j * sched.bi, sched.bi), a, :,
+                          pl.ds(csel * lanes + b * boc, boc)] = out
         if async_copies:
             out_dma(slot, j).start()
         return 0
@@ -250,8 +164,7 @@ def mm2im_db_tconv(
     copy + semaphores), ``'sync'`` (direct VMEM copies — the
     interpret-safe fallback), or ``'auto'`` (async unless
     ``REPRO_MM2IM_DB_SYNC=1``).  With ``fold_batch=True`` the two-slot
-    pipeline fetches batch-concatenated slabs and the grid is the
-    oc-block axis alone.
+    pipeline fetches batch-folded slabs and the batch grid axis is 1.
     """
     p = prepare_mm2im(
         x, w, bias, stride=stride, padding=padding, block_oh=block_oh,
@@ -266,60 +179,46 @@ def mm2im_db_tconv(
         raise ValueError(
             f"pipeline must be 'auto'|'async'|'sync', got {pipeline!r}")
     async_copies = pipeline == "async"
+    x_flat, w_taps, sched = stage_slabs(p)
 
     # j (the row-block sweep) is pipelined inside the kernel, so the grid is
-    # only the outer pair of the Alg. 1 loop nest — or, batch-folded, the
-    # oc-block axis alone (bcj/cbj collapse with the batch axis).
-    if p.fold_batch:
-        grid = (p.n_c,)
-        iw_ = lambda c: (0, 0, c)
-        ib = lambda c: (c,)
-        kernel = functools.partial(
-            _mm2im_db_folded_kernel,
-            b=p.b, n_j=p.n_j, block_oh=p.block_oh, oc_p=p.oc_p,
-            async_copies=async_copies, **p.kernel_kwargs())
-        scratch = [
-            pltpu.VMEM((_N_SLOTS, p.b, p.n_slab, p.iw, p.ic), p.x_p.dtype),
-            pltpu.VMEM((_N_SLOTS, p.b, p.block_oh, p.ow_p, p.boc),
-                       p.out_dtype),
-        ]
-    else:
-        if p.grid_order == "bcj":
-            grid = (p.b, p.n_c)
-            batch_axis = 0
-        else:  # "cbj"
-            grid = (p.n_c, p.b)
-            batch_axis = 1
-        iw_ = lambda *ids: (0, 0, ids[1 - batch_axis])
-        ib = lambda *ids: (ids[1 - batch_axis],)
-        kernel = functools.partial(
-            _mm2im_db_kernel,
-            batch_axis=batch_axis, n_j=p.n_j, block_oh=p.block_oh,
-            oc_p=p.oc_p, async_copies=async_copies, **p.kernel_kwargs())
-        scratch = [
-            pltpu.VMEM((_N_SLOTS * p.n_slab, p.iw, p.ic), p.x_p.dtype),
-            pltpu.VMEM((_N_SLOTS * p.block_oh, p.ow_p, p.boc), p.out_dtype),
-        ]
+    # only the outer pair of the Alg. 1 loop nest (bcj/cbj collapse when
+    # the batch is folded into the slab).
+    n_b = x_flat.shape[0]
+    if p.grid_order == "bcj" or p.fold_batch:
+        grid, batch_axis = (n_b, p.n_c), 0
+    else:  # "cbj"
+        grid, batch_axis = (p.n_c, n_b), 1
+    iw_ = lambda *ids: (0, 0, ids[1 - batch_axis])
+    ib = lambda *ids: (0, ids[1 - batch_axis])
+    kernel = functools.partial(
+        _mm2im_db_kernel, batch_axis=batch_axis, n_j=p.n_j,
+        async_copies=async_copies, sched=sched, boc=p.boc,
+        **p.epilogue_kwargs())
+    scratch = [
+        pltpu.VMEM((_N_SLOTS, sched.n_rows * sched.pitch, x_flat.shape[2]),
+                   x_flat.dtype),
+        pltpu.VMEM((_N_SLOTS, p.bi, p.s, sched.pitch, p.s * p.boc),
+                   p.out_dtype),
+    ]
     if async_copies:
         scratch += [pltpu.SemaphoreType.DMA((_N_SLOTS,)),
                     pltpu.SemaphoreType.DMA((_N_SLOTS,))]
 
-    any_space = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             any_space,  # x stays in HBM; slabs are DMA'd per row-block
-            pl.BlockSpec((p.ic, p.ks * p.ks, p.boc), iw_),
-            pl.BlockSpec((p.boc,), ib),
-            pl.BlockSpec((p.boc,), ib),
+            pl.BlockSpec(w_taps.shape[:2] + (p.boc,), iw_),
+            pl.BlockSpec((1, p.boc), ib),
+            pl.BlockSpec((1, p.boc), ib),
         ],
         out_specs=any_space,  # o written per row-block via the out pipeline
-        out_shape=jax.ShapeDtypeStruct(
-            (p.b, p.n_j * p.block_oh, p.ow_p, p.oc_p), p.out_dtype),
+        out_shape=slab_out_shape(p, sched),
         scratch_shapes=scratch,
         compiler_params=grid_semantics(len(grid), inner_arbitrary=False),
         interpret=p.interpret,
-    )(p.x_p, p.w3, p.bias_p, p.scales_p)
-
-    return out[:, :p.oh, :p.ow, :p.oc]
+    )(x_flat, w_taps, p.bias_p, p.scales_p)
+    return unstage_output(out, p, sched)

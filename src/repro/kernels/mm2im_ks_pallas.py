@@ -47,7 +47,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.segregate import Segregation, segregate
 from repro.kernels.mm2im_pallas import (MM2IMPrep, grid_semantics,
-                                        ppu_epilogue, prepare_mm2im)
+                                        mxu_precision, ppu_epilogue,
+                                        prepare_mm2im)
 
 
 def _sub_matmul(slab, w_ref, sk, *, m_rows: int, iw: int, boc: int,
@@ -64,6 +65,7 @@ def _sub_matmul(slab, w_ref, sk, *, m_rows: int, iw: int, boc: int,
     mm = jax.lax.dot_general(
         slab.reshape(m_rows * iw, ic), wsub.reshape(ic, sk.taps * boc),
         (((1,), (0,)), ((), ())),
+        precision=mxu_precision(slab.dtype),
         preferred_element_type=acc_dtype,
     )
     return mm.reshape(m_rows, iw, sk.jh, sk.jw, boc)
@@ -218,8 +220,8 @@ def mm2im_ks_tconv(
         in_specs = [
             pl.BlockSpec((p.b, p.ihp, p.iw, p.ic), lambda c, j: (0, 0, 0, 0)),
             pl.BlockSpec((p.ic, p.ks * p.ks, p.boc), lambda c, j: (0, 0, c)),
-            pl.BlockSpec((p.boc,), lambda c, j: (c,)),
-            pl.BlockSpec((p.boc,), lambda c, j: (c,)),
+            pl.BlockSpec((1, p.boc), lambda c, j: (0, c)),
+            pl.BlockSpec((1, p.boc), lambda c, j: (0, c)),
         ]
         out_specs = pl.BlockSpec((p.b, p.block_oh, p.ow_p, p.boc),
                                  lambda c, j: (0, j, 0, c))
@@ -230,19 +232,19 @@ def mm2im_ks_tconv(
             grid = (p.b, p.n_c, p.n_j)
             ix = lambda b_, c, j: (b_, 0, 0, 0)
             iw_ = lambda b_, c, j: (0, 0, c)
-            ib = lambda b_, c, j: (c,)
+            ib = lambda b_, c, j: (0, c)
             io = lambda b_, c, j: (b_, j, 0, c)
         else:  # "cbj"
             grid = (p.n_c, p.b, p.n_j)
             ix = lambda c, b_, j: (b_, 0, 0, 0)
             iw_ = lambda c, b_, j: (0, 0, c)
-            ib = lambda c, b_, j: (c,)
+            ib = lambda c, b_, j: (0, c)
             io = lambda c, b_, j: (b_, j, 0, c)
         in_specs = [
             pl.BlockSpec((1, p.ihp, p.iw, p.ic), ix),
             pl.BlockSpec((p.ic, p.ks * p.ks, p.boc), iw_),
-            pl.BlockSpec((p.boc,), ib),
-            pl.BlockSpec((p.boc,), ib),
+            pl.BlockSpec((1, p.boc), ib),
+            pl.BlockSpec((1, p.boc), ib),
         ]
         out_specs = pl.BlockSpec((1, p.block_oh, p.ow_p, p.boc), io)
         n_parallel = 2

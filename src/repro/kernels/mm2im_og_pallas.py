@@ -66,7 +66,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.segregate import Segregation, segregate
 from repro.kernels.mm2im_pallas import (MM2IMPrep, grid_semantics,
-                                        ppu_epilogue, prepare_mm2im)
+                                        mxu_precision, ppu_epilogue,
+                                        prepare_mm2im)
 
 
 def _og_gather(slab, sk, *, bi: int, iw: int, iw_p: int, delta: int):
@@ -119,6 +120,7 @@ def _og_plane(slab, w_ref, sk, *, b_fold: int, bi: int, iw: int, iw_p: int,
         g.reshape(b_fold * bi * iw_p, sk.taps * ic),
         wsub.reshape(sk.taps * ic, boc),
         (((1,), (0,)), ((), ())),
+        precision=mxu_precision(g.dtype),
         preferred_element_type=acc_dtype,
     )
     return mm.reshape(b_fold, bi, iw_p, boc)
@@ -250,8 +252,8 @@ def mm2im_og_tconv(
         in_specs = [
             pl.BlockSpec((p.b, p.ihp, p.iw, p.ic), lambda c, j: (0, 0, 0, 0)),
             pl.BlockSpec((p.ks * p.ks, p.ic, p.boc), lambda c, j: (0, 0, c)),
-            pl.BlockSpec((p.boc,), lambda c, j: (c,)),
-            pl.BlockSpec((p.boc,), lambda c, j: (c,)),
+            pl.BlockSpec((1, p.boc), lambda c, j: (0, c)),
+            pl.BlockSpec((1, p.boc), lambda c, j: (0, c)),
         ]
         out_specs = pl.BlockSpec((p.b, p.block_oh, p.ow_p, p.boc),
                                  lambda c, j: (0, j, 0, c))
@@ -262,19 +264,19 @@ def mm2im_og_tconv(
             grid = (p.b, p.n_c, p.n_j)
             ix = lambda b_, c, j: (b_, 0, 0, 0)
             iw_ = lambda b_, c, j: (0, 0, c)
-            ib = lambda b_, c, j: (c,)
+            ib = lambda b_, c, j: (0, c)
             io = lambda b_, c, j: (b_, j, 0, c)
         else:  # "cbj"
             grid = (p.n_c, p.b, p.n_j)
             ix = lambda c, b_, j: (b_, 0, 0, 0)
             iw_ = lambda c, b_, j: (0, 0, c)
-            ib = lambda c, b_, j: (c,)
+            ib = lambda c, b_, j: (0, c)
             io = lambda c, b_, j: (b_, j, 0, c)
         in_specs = [
             pl.BlockSpec((1, p.ihp, p.iw, p.ic), ix),
             pl.BlockSpec((p.ks * p.ks, p.ic, p.boc), iw_),
-            pl.BlockSpec((p.boc,), ib),
-            pl.BlockSpec((p.boc,), ib),
+            pl.BlockSpec((1, p.boc), ib),
+            pl.BlockSpec((1, p.boc), ib),
         ]
         out_specs = pl.BlockSpec((1, p.block_oh, p.ow_p, p.boc), io)
         n_parallel = 2

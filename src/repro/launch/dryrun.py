@@ -27,7 +27,7 @@ import jax  # noqa: E402
 
 from repro.configs import registry  # noqa: E402
 from repro.distributed import hlo  # noqa: E402
-from repro.launch.mesh import make_production_mesh, use_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh, make_production_mesh  # noqa: E402
 from repro.runtime import steps as steps_mod  # noqa: E402
 
 
@@ -42,7 +42,7 @@ def _mesh(multi_pod: bool):
     if override:
         dims = tuple(int(x) for x in override.split("x"))
         axes = ("pod", "data", "model")[-len(dims):]
-        return jax.make_mesh(dims, axes)
+        return make_mesh(dims, axes)
     return make_production_mesh(multi_pod=multi_pod)
 
 
@@ -53,7 +53,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, verbose: bool = True):
            "devices": mesh.devices.size}
     t0 = time.time()
     try:
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             bundle = steps_mod.make_step_for_cell(arch, shape, mesh)
             lowered = bundle.fn.lower(*bundle.abstract_args)
             t_lower = time.time() - t0

@@ -16,31 +16,24 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests use small ones, e.g. (2, 2))."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape, axes, *, devices=None):
+    """Arbitrary mesh (tests use small ones, e.g. (2, 2)).
+
+    Every axis is ``Auto``: the model code places data with sharding
+    constraints and lets the partitioner propagate them, so activations
+    carry no sharding in their types.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Mesh over whatever devices exist (CPU smoke: 1 device)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` across jax versions.
-
-    Newer jax exposes ``jax.set_mesh`` (a context manager); on older
-    releases (e.g. 0.4.x) ``Mesh`` itself is the context manager that sets
-    the physical mesh for bare-``PartitionSpec`` sharding constraints.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    return make_mesh((n // model_parallel, model_parallel), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:

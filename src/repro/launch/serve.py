@@ -17,7 +17,6 @@ import jax.numpy as jnp
 
 from repro.configs import registry
 from repro.models import lm
-from repro.launch.mesh import use_mesh
 from repro.runtime.fault_tolerance import elastic_mesh
 
 
@@ -36,7 +35,7 @@ def main() -> None:
     mesh = elastic_mesh(args.model_parallel)
     max_seq = args.prompt_len + args.tokens
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params, _ = lm.init(cfg, jax.random.PRNGKey(0))
         rng = jax.random.PRNGKey(1)
         prompt = jax.random.randint(rng, (args.batch, args.prompt_len), 0,

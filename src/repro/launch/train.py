@@ -18,7 +18,6 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.optim import adamw
 from repro.models import lm
 from repro.runtime import steps as steps_mod
-from repro.launch.mesh import use_mesh
 from repro.runtime.fault_tolerance import (LoopConfig, PreemptionSimulator,
                                            TrainLoop, elastic_mesh)
 
@@ -47,7 +46,7 @@ def main() -> None:
     opt_cfg = adamw.AdamWConfig(lr=args.lr, total_steps=args.steps,
                                 warmup_steps=max(args.steps // 10, 1),
                                 state_dtype=cfg.opt_state_dtype)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = steps_mod.make_train_step(cfg, mesh, opt_cfg,
                                            batch=args.batch, seq=args.seq)
         params, specs = lm.init(cfg, jax.random.PRNGKey(0))

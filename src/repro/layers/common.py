@@ -191,7 +191,15 @@ def init_embedding(key, vocab: int, d_model: int, dtype=jnp.float32,
 
 
 def embed(params, tokens):
-    return jnp.take(params["table"], tokens, axis=0)
+    """Row gather that keeps the tokens' batch sharding.
+
+    Under an explicit-sharding mesh the gather's output sharding is
+    ambiguous (vocab-sharded table, data-sharded tokens), so it is spelled
+    out: the tokens' spec with the feature dim replicated.
+    """
+    sharding = jax.typeof(tokens).sharding
+    return params["table"].at[tokens].get(
+        out_sharding=sharding.update(spec=P(*sharding.spec, None)))
 
 
 def unembed(params, x, vocab: Optional[int] = None):

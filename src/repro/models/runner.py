@@ -235,6 +235,7 @@ class GeneratorRunner:
         self.options.update(options)
         self._quant: Optional[Dict[str, LayerQuant]] = None
         self._jitted: Dict[tuple, Callable] = {}
+        self._jit_fns: Dict[tuple, Callable] = {}
         self._warm: set = set()
 
     @property
@@ -334,7 +335,15 @@ class GeneratorRunner:
                     self._warm.add(_key)
 
             self._jitted[key] = fn
+            self._jit_fns[key] = jfn
         return fn
+
+    def lower(self, *, batch: int, precision: str = "f32"):
+        """Lower the bucket's jitted forward — the program :meth:`jitted`
+        runs — e.g. to count the kernels in ``.compile().as_text()``."""
+        self.jitted(batch=batch, precision=precision)
+        return self._jit_fns[(int(batch), precision)].lower(
+            self.params, self.input_spec(batch))
 
     def has_compiled(self, *, batch: int, precision: str = "f32") -> bool:
         """Whether the (batch, precision) bucket has executed at least once

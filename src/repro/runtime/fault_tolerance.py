@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import DataConfig, make_batch
+from repro.launch.mesh import make_mesh
 
 
 class Preempted(RuntimeError):
@@ -100,8 +101,8 @@ def elastic_mesh(model_parallel: int = 1, devices=None):
     mp = min(model_parallel, n)
     while n % mp:
         mp -= 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"),
-                         devices=devices[: (n // mp) * mp])
+    return make_mesh((n // mp, mp), ("data", "model"),
+                     devices=devices[: (n // mp) * mp])
 
 
 @dataclasses.dataclass

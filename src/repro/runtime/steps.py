@@ -208,9 +208,7 @@ def make_train_step_compressed(cfg: ModelConfig, mesh,
     state_pod_specs = jax.tree.map(lambda _: P(), astate)
     err_pod_specs = jax.tree.map(lambda _: P("pod"), astate["params"])
     batch_pod_specs = jax.tree.map(lambda _: P("pod"), abatch)
-    from repro.compat import shard_map
-
-    fn_sm = shard_map(
+    fn_sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(state_pod_specs, err_pod_specs, batch_pod_specs),
         out_specs=(state_pod_specs, err_pod_specs,

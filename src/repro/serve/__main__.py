@@ -12,8 +12,10 @@ Resilience knobs (``serve/resilience.py``): ``--max-queue-depth`` bounds
 each bucket's queue (overflow sheds), ``--deadline-ms`` attaches a
 per-request deadline, and ``--chaos-fail-nth`` injects a deterministic
 transient fault every Nth batch to exercise the degradation ladder.
-Exit status is **nonzero when any bucket ends with ``failed > 0``** (the
-full stats dump goes to stdout first), so the CI smoke legs can assert
+Exit status is **nonzero when any bucket ends with ``failed > 0``**, or
+when a bucket served any batch below the tuned rung although no fault was
+injected (a kernel that fails to compile must not hide behind the ladder).
+The full stats dump goes to stdout first, so the CI smoke legs can assert
 healthy runs with a plain shell check.
 """
 
@@ -27,8 +29,9 @@ import time
 import jax
 import numpy as np
 
-from repro.serve.resilience import FaultInjector, ResilienceConfig
+from repro.compile_cache import enable_compile_cache
 from repro.models.runner import make_runner
+from repro.serve.resilience import FaultInjector, ResilienceConfig
 from repro.serve.server import TconvServer
 
 SMOKE_RUNNERS = {
@@ -58,6 +61,7 @@ def main() -> int:
                          "(degradation-ladder smoke)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = [m for m in args.models.split(",") if m]
     precisions = tuple(p for p in args.precisions.split(",") if p)
@@ -120,6 +124,12 @@ def main() -> int:
            if b["failed"] > 0}
     if bad:
         print(f"[serve] FAILED buckets: {bad}", file=sys.stderr)
+        return 1
+    degraded = {key: b["rungs"] for key, b in stats["buckets"].items()
+                if b["degraded"] > 0}
+    if degraded and injector is None:
+        print(f"[serve] DEGRADED buckets with no fault injected: {degraded}",
+              file=sys.stderr)
         return 1
     return 0
 

@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 from repro.checkpoint.manager import CheckpointManager
-from repro.launch.mesh import use_mesh
 from repro.configs import registry
 from repro.data.pipeline import DataConfig
 from repro.models import lm
@@ -22,7 +21,7 @@ def _setup(tmp_path, total=8, ckpt_every=2):
     cfg = registry.get("qwen2.5-3b").smoke
     mesh = elastic_mesh(1)
     opt_cfg = adamw.AdamWConfig(lr=1e-3, warmup_steps=0, schedule="constant")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = steps_mod.make_train_step(cfg, mesh, opt_cfg, batch=2, seq=16,
                                            donate=False)
         params, specs = lm.init(cfg, jax.random.PRNGKey(0))
@@ -40,13 +39,13 @@ def _setup(tmp_path, total=8, ckpt_every=2):
 def test_preemption_then_resume_bit_exact(tmp_path):
     # Uninterrupted run.
     loop_a, mesh = _setup(tmp_path / "a")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state_a, _ = loop_a.run()
 
     # Interrupted at step 5, then resumed.
     loop_b, _ = _setup(tmp_path / "b")
     loop_b.preempt = PreemptionSimulator(at_step=5)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         with pytest.raises(Preempted):
             loop_b.run()
         loop_c, _ = _setup(tmp_path / "b")

@@ -8,6 +8,7 @@ from repro.core import perf_model
 from repro.core.maps import TConvProblem, drop_stats
 from repro.kernels import ref
 from repro.kernels.baselines import zero_insertion_macs
+from repro.kernels.mm2im_pallas import lane_block
 
 PROBLEMS = [TConvProblem(8, 8, 64, 5, 32, 2), TConvProblem(16, 16, 32, 3, 16, 1)]
 
@@ -85,9 +86,10 @@ def test_int8_without_requant_stores_int32():
     p = PROBLEMS[0]
     e_req = perf_model.mm2im_estimate(p, bits=8, requant=True)
     e_raw = perf_model.mm2im_estimate(p, bits=8, requant=False)
-    out_elems = p.oh * (-(-p.ow // p.stride) * p.stride) * p.oc  # padded ow
-    # Same traffic everywhere except the store width: 3 extra bytes/elem
-    # (oc padding may add more; at these shapes oc tiles exactly).
+    # Padded ow, and O_c padded to the 128-lane block the kernel stores.
+    out_elems = (p.oh * (-(-p.ow // p.stride) * p.stride)
+                 * lane_block(p.oc, p.oc))
+    # Same traffic everywhere except the store width: 3 extra bytes/elem.
     assert e_raw.hbm_bytes - e_req.hbm_bytes == 3 * out_elems
     # Default models the paper's precision (requantizing int8).
     assert perf_model.mm2im_estimate(p, bits=8).hbm_bytes == e_req.hbm_bytes

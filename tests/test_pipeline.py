@@ -7,7 +7,6 @@ import sys
 
 import pytest
 
-from repro.compat import HAS_NATIVE_SHARD_MAP
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -15,7 +14,6 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import use_mesh
 from repro.distributed.pipeline import pipeline_apply
 
 for S, M in [(2, 4), (4, 6), (2, 2)]:
@@ -28,7 +26,7 @@ for S, M in [(2, 4), (4, 6), (2, 2)]:
     params = {"w": W, "b": b}
     x = jax.random.normal(jax.random.fold_in(k, 2), (M, mb, D))
     stage_fn = lambda p, a: jnp.tanh(a @ p["w"] + p["b"])
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y = jax.jit(lambda pp, xx: pipeline_apply(
             stage_fn, pp, xx, mesh=mesh))(params, x)
     ref = x
@@ -41,9 +39,6 @@ for S, M in [(2, 4), (4, 6), (2, 2)]:
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not HAS_NATIVE_SHARD_MAP,
-    reason="partial-manual shard_map unsupported by jax 0.4.x SPMD (PartitionId)")
 def test_gpipe_matches_sequential():
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
     out = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,

@@ -282,6 +282,36 @@ def test_autotune_and_execute_through_ops(tmp_path):
     assert PlanCache(tmp_path / "tune.json").get(res.key) == res.plan
 
 
+def test_autotune_never_times_a_plan_the_compiler_refuses(tmp_path,
+                                                         monkeypatch):
+    """Plans the chip's compiler refuses are skipped in rank order: the
+    autotuner still times ``max_measure`` plans, none of them refused."""
+    from repro.core import autotune, tiling
+
+    p = TConvProblem(16, 16, 32, 3, 16, 1)
+    timed = []
+
+    def fake_measure(problem, plan, **kw):
+        timed.append(plan)
+        return 1.0 + len(timed)
+
+    refused = []
+
+    def compiles(problem, plan, **kw):  # refuse every other plan asked
+        asked = compiles.calls = getattr(compiles, "calls", 0) + 1
+        if asked % 2:
+            refused.append(plan)
+        return not asked % 2
+
+    monkeypatch.setattr(tiling, "family_compiles", lambda *a, **kw: True)
+    monkeypatch.setattr(tiling, "plan_compiles", compiles)
+    monkeypatch.setattr(autotune, "measure_plan", fake_measure)
+    res = autotune_result(p, cache=PlanCache(tmp_path / "tune.json"),
+                          max_measure=3, repeats=1)
+    assert len(timed) == 3 and res.n_measured == 3
+    assert refused and not set(refused) & set(timed), (refused, timed)
+
+
 def test_tconv_int8_explicit_bad_plan_raises():
     """tconv_int8 surfaces the same block_oh-vs-stride ValueError as tconv
     for an explicit plan, instead of deferring to a deeper kernel assert."""
@@ -311,9 +341,9 @@ def test_bwd_zero_bias_keeps_weight_dtype():
 def test_default_plan_matches_heuristic():
     p = TConvProblem(8, 8, 16, 5, 12, 2)
     d = default_plan(p)
-    from repro.kernels.mm2im_pallas import plan_blocks
+    from repro.kernels.mm2im_pallas import VMEM_BUDGET, plan_blocks
     boh, boc = plan_blocks(p.ih, p.iw, p.ic, p.ks, p.oc, p.stride, p.padding,
-                           vmem_budget=int(0.75 * 16 * 2**20), in_bytes=4)
+                           vmem_budget=VMEM_BUDGET, in_bytes=4)
     assert (d.block_oh, d.block_oc) == (boh, boc)
 
 

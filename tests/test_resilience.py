@@ -537,3 +537,46 @@ def test_real_model_heuristic_rung_output_matches_reference(fsrcnn_runner):
     lax = np.asarray(ladder.fn(RUNG_LAX, batch=2, precision="f32")(x))
     np.testing.assert_allclose(heur, tuned, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(lax, tuned, rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# `python -m repro.serve` exit status: degradation never passes quietly.
+# ---------------------------------------------------------------------------
+
+
+def _refuse_tuned_rung(monkeypatch):
+    """Every tuned-rung forward raises, as a kernel the chip's compiler
+    refuses would; the heuristic rung still serves."""
+    build = DegradationLadder._build
+
+    def build_or_refuse(self, rung, *, batch, precision):
+        if rung != RUNG_TUNED:
+            return build(self, rung, batch=batch, precision=precision)
+
+        def refused(x):
+            raise RuntimeError("tuned kernel refused by the compiler")
+        return refused
+
+    monkeypatch.setattr(DegradationLadder, "_build", build_or_refuse)
+
+
+@pytest.mark.parametrize("refuse,chaos,code", [
+    (False, False, 0),   # healthy run
+    (True, False, 1),    # degraded with no fault injected: fails the run
+    (True, True, 0),     # the chaos leg degrades on purpose
+])
+def test_serve_cli_exit_status_on_degraded_batches(monkeypatch, refuse,
+                                                    chaos, code):
+    import sys
+
+    from repro.serve import __main__ as cli
+
+    monkeypatch.setattr(cli, "enable_compile_cache", lambda: "")
+    if refuse:
+        _refuse_tuned_rung(monkeypatch)
+    argv = ["repro.serve", "--models", "fsrcnn", "--precisions", "f32",
+            "--requests", "4", "--rate", "1000"]
+    if chaos:
+        argv += ["--chaos-fail-nth", "1"]
+    monkeypatch.setattr(sys, "argv", argv)
+    assert cli.main() == code

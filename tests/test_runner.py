@@ -210,6 +210,11 @@ def test_jitted_memoized_and_warm_tracking(runners):
     assert r.has_compiled(batch=2, precision="f32")
     np.testing.assert_allclose(out, np.asarray(r.apply(
         r.example_inputs(batch=2))), rtol=1e-5, atol=1e-5)
+    # lower() gives the bucket's program itself (what chip_smoke.py counts
+    # Mosaic kernels in): same result on the same inputs.
+    compiled = r.lower(batch=2).compile()
+    np.testing.assert_array_equal(
+        np.asarray(compiled(r.params, r.example_inputs(batch=2))), out)
 
 
 # ---------------------------------------------------------------------------

@@ -436,3 +436,31 @@ def test_server_stop_fails_unservable_requests_typed():
     s = server.stats()
     [b] = s["buckets"].values()
     assert b["failed"] == 3 and s["pending"] == 0
+
+
+# ---------------------------------------------------------------------------
+# Compile cache placement (entry points call enable_compile_cache first).
+# ---------------------------------------------------------------------------
+
+
+def test_compile_cache_goes_where_the_environment_says(monkeypatch):
+    from pathlib import Path
+
+    from repro import compile_cache
+
+    updates = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    # Set from outside: JAX reads the variable itself; no other path is set.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/by/caller")
+    assert compile_cache.enable_compile_cache() == "/placed/by/caller"
+    assert updates == []
+    # Unset: the same fixed directory in the checkout, every run, and git
+    # ignores it.
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    first = compile_cache.enable_compile_cache()
+    assert first == compile_cache.enable_compile_cache()
+    root = Path(__file__).resolve().parents[1]
+    assert Path(first) == root / ".jax_cache"
+    assert updates == [("jax_compilation_cache_dir", first)] * 2
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()

@@ -11,9 +11,7 @@ import numpy as np
 import pytest
 
 from repro.configs import registry
-from repro.compat import HAS_NATIVE_SHARD_MAP
 from repro.data.pipeline import DataConfig, make_batch
-from repro.launch.mesh import use_mesh
 from repro.models import lm
 from repro.optim import adamw
 from repro.runtime import steps as steps_mod
@@ -27,7 +25,7 @@ def test_lm_training_reduces_loss():
     mesh = elastic_mesh(1)
     opt_cfg = adamw.AdamWConfig(lr=3e-3, warmup_steps=2, total_steps=40,
                                 schedule="constant")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = steps_mod.make_train_step(cfg, mesh, opt_cfg, batch=4,
                                            seq=32, donate=False)
         params, _ = lm.init(cfg, jax.random.PRNGKey(0))
@@ -44,7 +42,7 @@ def test_lm_training_reduces_loss():
 def test_prefill_step_runs():
     cfg = registry.get("internvl2-1b").smoke
     mesh = elastic_mesh(1)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = steps_mod.make_prefill_step(cfg, mesh, batch=2, seq=16)
         params, _ = lm.init(cfg, jax.random.PRNGKey(0))
         batch = {
@@ -59,7 +57,7 @@ def test_prefill_step_runs():
 def test_decode_step_runs_and_advances_cache():
     cfg = registry.get("recurrentgemma-9b").smoke
     mesh = elastic_mesh(1)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = steps_mod.make_decode_step(cfg, mesh, batch=2, seq=32)
         params, _ = lm.init(cfg, jax.random.PRNGKey(0))
         cache = lm.init_cache(cfg, 2, 32, length=8)
@@ -82,9 +80,6 @@ def test_dryrun_cell_subprocess():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not HAS_NATIVE_SHARD_MAP,
-    reason="partial-manual shard_map unsupported by jax 0.4.x SPMD")
 def test_compressed_pod_trainstep_subprocess():
     """int8 cross-pod gradient compression: compile + run on a 2x2x2 mesh."""
     script = r"""
@@ -95,11 +90,10 @@ from repro.configs import registry
 from repro.runtime import steps as steps_mod
 from repro.models import lm
 from repro.optim import adamw
-from repro.launch.mesh import use_mesh
 from repro.data.pipeline import DataConfig, make_batch
 cfg = registry.get("qwen2.5-3b").smoke
 mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     b = steps_mod.make_train_step_compressed(cfg, mesh, batch=4, seq=16)
     params, specs = lm.init(cfg, jax.random.PRNGKey(0))
     opt_cfg = adamw.AdamWConfig(state_dtype=cfg.opt_state_dtype)

@@ -139,6 +139,70 @@ def test_candidate_plans_db_variant_coverage():
     assert db.vmem_bytes < sb.vmem_bytes
 
 
+def test_candidate_plans_skip_families_the_chip_refuses(monkeypatch):
+    """On a TPU, a family whose kernel Mosaic refuses for this problem is
+    never offered to the autotuner (so it is never timed as a loser)."""
+    p = TConvProblem(16, 16, 32, 3, 16, 1)
+    probed = []
+
+    def compiles(method, problem, batch, bits, *geometry):
+        probed.append((method, problem, batch, bits))
+        return method != "mm2im_og"
+
+    monkeypatch.setattr(tiling.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(tiling, "_compiles_on_device", compiles)
+    methods = {c.method for c in tiling.candidate_plans(p, batch=8)}
+    assert methods == {"mm2im", "mm2im_db", "mm2im_ks"}
+    assert ("mm2im_og", p, 8, 8) in probed
+
+
+def test_compile_probe_drops_only_compiler_refusals(monkeypatch):
+    """A compiler refusal drops the plan with one warning that names the
+    family and the problem; any other error is a bug and propagates."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels import ops
+
+    p = TConvProblem(4, 4, 8, 3, 8, 2)
+    probe = tiling._compiles_on_device.__wrapped__
+
+    def refuse(*args, **kw):
+        raise pltpu.LoweringException("unsupported shape cast")
+
+    monkeypatch.setattr(ops, "run_registered", refuse)
+    with pytest.warns(RuntimeWarning, match=r"mm2im_og .*TConvProblem"
+                      r".*unsupported shape cast"):
+        assert probe("mm2im_og", p, 1, 32, 2, 128, False) is False
+
+    def broken(*args, **kw):
+        raise TypeError("bad argument")
+
+    monkeypatch.setattr(ops, "run_registered", broken)
+    with pytest.raises(TypeError, match="bad argument"):
+        probe("mm2im_og", p, 1, 32, 2, 128, False)
+
+
+def test_plan_compiles_keys_on_geometry_not_grid_order(monkeypatch):
+    """On a TPU each (family, geometry, fold) is compiled once; the grid
+    order shares the answer, and off the chip every plan is accepted."""
+    from repro.kernels.registry import Plan
+
+    p = TConvProblem(16, 16, 32, 3, 16, 1)
+    assert tiling.plan_compiles(p, Plan(4, 128, "cbj", "mm2im_ks"))
+    probed = []
+
+    def compiles(*key):
+        probed.append(key)
+        return key[4] != 8  # refuse block_oh 8
+
+    monkeypatch.setattr(tiling.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(tiling, "_compiles_on_device", compiles)
+    assert tiling.plan_compiles(p, Plan(4, 128, "cbj", "mm2im_ks"), bits=32)
+    assert not tiling.plan_compiles(p, Plan(8, 128, "bcj"), batch=2, bits=32)
+    assert probed == [("mm2im_ks", p, 1, 32, 4, 128, False),
+                      ("mm2im", p, 2, 32, 8, 128, False)]
+
+
 def test_explicit_plan_override_roundtrip():
     p = TConvProblem(8, 8, 16, 5, 12, 2)
     tp = tiling.plan(p, block_oh=4, block_oc=8, grid_order="cbj")
