@@ -33,7 +33,6 @@ MODULES = [
     ("ablations", "benchmarks.bench_ablations"),              # kernel ablations
     ("autotune", "benchmarks.bench_autotune"),                # tuned vs default plans
     ("scale_roofline", "benchmarks.bench_scale_roofline"),    # §Roofline
-    ("serve_tconv", "benchmarks.bench_serve_tconv"),          # serving trajectory
 ]
 
 
@@ -84,21 +83,10 @@ def mm2im_summary(rows: list) -> dict:
     * ``large_image`` — the og-vs-mm2im-vs-ks cross-method head-to-heads
       on the >=32x32 stride-4 regime (``autotune_large_*_ogcmp``), parsed
       so the gather-family trajectory diffs at a glance (the raw rows
-      also stay in ``autotune`` for the rank-agreement gate);
-    * ``serve`` — every ``serve*`` row from ``bench_serve_tconv`` with its
-      derived fields parsed (batched-vs-sequential speedup, batch-fill
-      ratio, wait-bound flag), so the serving trajectory diffs alongside
-      the kernel one;
-    * ``serve_chaos`` — the fault-injected degraded-mode rows
-      (``serve_chaos_*``: ladder rung counts, shed/expired/breaker
-      counters) kept in their *own* section: ``tools/bench_gate.py``
-      ignores it for latency banding — degraded-mode latency is the
-      injected fault's artifact, not a kernel regression signal.
+      also stay in ``autotune`` for the rank-agreement gate).
     """
     methods = {}
     autotune_rows = []
-    serve = {}
-    serve_chaos = {}
     large_image = {}
     tier_hits = None
     for r in rows:
@@ -115,10 +103,6 @@ def mm2im_summary(rows: list) -> dict:
             autotune_rows.append(r)
             if name.startswith("autotune_large_"):
                 large_image[name] = _parse_derived(r["derived"])
-        elif name.startswith("serve_chaos"):
-            serve_chaos[name] = _parse_derived(r["derived"])
-        elif name.startswith("serve"):
-            serve[name] = _parse_derived(r["derived"])
 
     from repro.configs.paper_models import TABLE_II
     from repro.core.perf_model import mm2im_estimate
@@ -142,15 +126,14 @@ def mm2im_summary(rows: list) -> dict:
 
     return {"methods": methods, "autotune": autotune_rows,
             "tier_hits": tier_hits, "modeled_fold_b8": modeled,
-            "rank_agreement": rank, "large_image": large_image,
-            "serve": serve, "serve_chaos": serve_chaos}
+            "rank_agreement": rank, "large_image": large_image}
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated module names to run "
-                         "(e.g. --only autotune,serve_tconv)")
+                         "(e.g. --only autotune,model_layers)")
     ap.add_argument("--json", default=None, metavar="OUT",
                     help="also write the emitted rows + run metadata as JSON "
                          "(the CI perf-trajectory artifact)")

@@ -4,9 +4,10 @@
 
 Builds CPU-sized runners, warms every (model, precision) bucket, pushes
 open-loop synthetic traffic through the background drain thread, and
-prints the per-bucket stats snapshot.  The measured version of this loop
-(arrival-rate x image-size x precision sweep, percentile reporting) is
-``benchmarks/bench_serve_tconv.py``.
+prints the per-bucket stats snapshot.  Serving is measured on the chip
+by ``chipbench/`` (``chipbench/README.md``); where the server's time goes
+is in its ``serve.*`` spans and counters (``serve/telemetry.py``,
+DESIGN.md §9.3), which a profile of any running server records.
 
 Resilience knobs (``serve/resilience.py``): ``--max-queue-depth`` bounds
 each bucket's queue (overflow sheds), ``--deadline-ms`` attaches a

@@ -49,7 +49,7 @@ class Request:
     """One in-flight request: payload + a thread-safe result slot."""
 
     __slots__ = ("rid", "model", "inputs", "precision", "t_enqueue",
-                 "deadline", "t_done", "_event", "_value", "_error")
+                 "deadline", "t_done", "batch", "_event", "_value", "_error")
 
     def __init__(self, rid: int, model: str, inputs, precision: str,
                  t_enqueue: float, deadline: Optional[float] = None):
@@ -63,6 +63,9 @@ class Request:
         # executed (serve/resilience.py).
         self.deadline = deadline
         self.t_done: Optional[float] = None
+        # Index of the batch that served it (the server's ``batch`` span
+        # id), None until its batch forms.
+        self.batch: Optional[int] = None
         self._event = threading.Event()
         self._value = _UNSET
         self._error: Optional[BaseException] = None
@@ -175,6 +178,14 @@ class Batcher:
                     out.append((self._specs[key], list(q)))
                     q.clear()
         return out
+
+    def due(self, reqs: list, reason: str, now: float) -> float:
+        """When a batch that :meth:`ready` popped at ``now`` became due: a
+        full batch at its last member's enqueue, a deadline flush once its
+        head had waited ``max_wait_s``, a forced flush at ``now``."""
+        if reason == FLUSH_FULL:
+            return reqs[-1].t_enqueue
+        return min(reqs[0].t_enqueue + self.max_wait_s, now)
 
     def ready(self, now: float, *,
               force: bool = False) -> List[Tuple[BucketSpec, list, str]]:

@@ -64,6 +64,7 @@ import numpy as np
 from repro.runtime.fault_tolerance import StragglerSimulator, jittered_backoff
 from repro.serve.bucketing import (AdmissionError, CircuitOpenError,
                                    QueueFullError, ShedError)
+from repro.serve.telemetry import phase
 
 __all__ = [
     "AdmissionError", "CircuitBreaker", "CircuitOpenError", "DeadlineExceeded",
@@ -283,6 +284,7 @@ class DegradationLadder:
     def __init__(self, runner):
         self.runner = runner
         self._fns: Dict[tuple, Callable] = {}
+        self._warm: set = set()  # built-here rungs' keys that have run
         self._lock = threading.Lock()
 
     def rungs(self, precision: str) -> Tuple[str, ...]:
@@ -297,6 +299,15 @@ class DegradationLadder:
             with self._lock:
                 f = self._fns.setdefault(key, f)
         return f
+
+    def compiled(self, rung: str, *, batch: int, precision: str) -> bool:
+        """Whether the rung has run at this batch, so that a call of it
+        is a jit-cache hit."""
+        if rung == RUNG_TUNED:
+            return self.runner.has_compiled(batch=batch, precision=precision)
+        if rung == RUNG_F32:
+            return self.runner.has_compiled(batch=batch, precision="f32")
+        return (rung, int(batch), precision) in self._warm
 
     def _build(self, rung: str, *, batch: int, precision: str) -> Callable:
         r = self.runner
@@ -318,7 +329,14 @@ class DegradationLadder:
             raise ValueError(f"unknown ladder rung {rung!r}")
         jfn = jax.jit(functools.partial(r.spec.forward, options=r.options,
                                         policy=policy))
-        return lambda x, _jfn=jfn: _jfn(r.params, x)
+        key = (rung, int(batch), precision)
+
+        def fn(x):
+            out = jfn(r.params, x)
+            self._warm.add(key)
+            return out
+
+        return fn
 
 
 # ---------------------------------------------------------------------------
@@ -419,19 +437,22 @@ def run_ladder(ladder: DegradationLadder, xs, *, bucket: str, batch: int,
                config: ResilienceConfig,
                injector: Optional[FaultInjector] = None,
                rng: Optional[np.random.Generator] = None,
-               sleep: Callable[[float], None] = time.sleep
-               ) -> Tuple[np.ndarray, str, int]:
+               sleep: Callable[[float], None] = time.sleep,
+               stats=None) -> Tuple[np.ndarray, str, int]:
     """Execute one batch down the ladder; ``(output, rung, retries)``.
 
     Per rung: one attempt, plus one backoff-jittered retry when the fault
     is transient (``is_transient``) and retries are enabled.  Exhausting
     every rung raises :class:`LadderExhausted` chained onto the last
     rung's error — the server fails the batch's requests with it and
-    feeds the breaker.
+    feeds the breaker.  The copy in, each call and the copy out are the
+    ``serve.put``, ``serve.dispatch`` and ``serve.fetch`` phases
+    (``serve/telemetry.py``), counted in ``stats`` when it is given.
     """
     retries = 0
     last: Optional[BaseException] = None
-    x_dev = jnp.asarray(xs)
+    with phase("serve.put", stats, batch=batch_index):
+        x_dev = jnp.asarray(xs)
     for rung in ladder.rungs(precision):
         try:
             fn = ladder.fn(rung, batch=batch, precision=precision)
@@ -440,14 +461,20 @@ def run_ladder(ladder: DegradationLadder, xs, *, bucket: str, batch: int,
             continue
         for attempt in (0, 1):
             try:
-                if injector is not None:
-                    injector.before_batch(bucket, batch_index, rung=rung,
-                                          attempt=attempt)
-                    call = injector.wrap(fn, bucket, batch_index, rung=rung,
-                                         attempt=attempt)
-                else:
-                    call = fn
-                return np.asarray(call(x_dev)), rung, retries
+                with phase("serve.dispatch", stats, batch=batch_index,
+                           rung=rung, attempt=attempt,
+                           compiled=ladder.compiled(rung, batch=batch,
+                                                    precision=precision)):
+                    if injector is not None:
+                        injector.before_batch(bucket, batch_index, rung=rung,
+                                              attempt=attempt)
+                        call = injector.wrap(fn, bucket, batch_index,
+                                             rung=rung, attempt=attempt)
+                    else:
+                        call = fn
+                    out = call(x_dev)
+                with phase("serve.fetch", stats, batch=batch_index):
+                    return np.asarray(out), rung, retries
             except Exception as err:  # noqa: BLE001 — every rung may fail
                 last = err
                 if (attempt == 0 and config.retry_transient

@@ -31,7 +31,10 @@ on a lower rung), or fails with a typed error.
 
 Execution is synchronous under the hood (``serve_once``) so tests can
 drive the server deterministically with an injected clock; ``start()``
-wraps the same drain in a daemon thread for real traffic.
+wraps the same drain in a daemon thread for real traffic.  Admission and
+each step of the drain loop are ``serve.*`` phases
+(``serve/telemetry.py``, DESIGN.md §9.3): profiler spans, and the same
+seconds as cumulative counters in ``stats()``.
 
 Numerics caveat: the models compute batch statistics inline (see
 ``models/gan.py``), so outputs depend on batch composition — a padded
@@ -56,6 +59,7 @@ from repro.serve.bucketing import (AdmissionError, BucketKey, BucketSpec,
 from repro.serve.resilience import (CircuitBreaker, DeadlineExceeded,
                                     FaultInjector, ResilienceConfig,
                                     RUNG_TUNED)
+from repro.serve.telemetry import phase
 
 
 class ServerClosed(RuntimeError):
@@ -67,8 +71,9 @@ class _BucketStats:
 
     __slots__ = ("requests", "completed", "failed", "batches", "flush_full",
                  "flush_deadline", "fill_sum", "wait_sum", "wait_max",
-                 "compile_hits", "shed", "deadline_expired", "retries",
-                 "degraded", "rungs")
+                 "pad_s", "put_s", "dispatch_s", "fetch_s", "fulfil_s",
+                 "due_wait_s", "compile_hits", "shed", "deadline_expired",
+                 "retries", "degraded", "rungs")
 
     def __init__(self):
         self.requests = 0       # successfully enqueued (excludes sheds)
@@ -80,6 +85,16 @@ class _BucketStats:
         self.fill_sum = 0.0
         self.wait_sum = 0.0
         self.wait_max = 0.0
+        # Seconds of the drain thread's steps of this bucket's batches
+        # (``telemetry.COUNTERS``), and of its due batches waiting for the
+        # drain thread: from when each became due (``Batcher.due``) to
+        # the start of its ``serve.pad``.
+        self.pad_s = 0.0
+        self.put_s = 0.0
+        self.dispatch_s = 0.0
+        self.fetch_s = 0.0
+        self.fulfil_s = 0.0
+        self.due_wait_s = 0.0
         self.compile_hits = 0
         self.shed = 0           # rejected at admission for load (not queued)
         self.deadline_expired = 0
@@ -105,6 +120,12 @@ class _BucketStats:
             "queue_wait_mean_s": (self.wait_sum / self.completed
                                   if self.completed else 0.0),
             "queue_wait_max_s": self.wait_max,
+            "pad_s": self.pad_s,
+            "put_s": self.put_s,
+            "dispatch_s": self.dispatch_s,
+            "fetch_s": self.fetch_s,
+            "fulfil_s": self.fulfil_s,
+            "due_wait_s": self.due_wait_s,
             "compile_hits": self.compile_hits,
             "shed": self.shed,
             "deadline_expired": self.deadline_expired,
@@ -113,6 +134,18 @@ class _BucketStats:
             "rungs": dict(self.rungs),
             "breaker": breaker.snapshot() if breaker is not None else None,
         }
+
+
+class _LoopStats:
+    """Server-wide counters of the drain loop, written by its thread."""
+
+    __slots__ = ("form_s", "drain_wait_s", "ticks", "empty_ticks")
+
+    def __init__(self):
+        self.form_s = 0.0        # ``serve.form`` seconds
+        self.drain_wait_s = 0.0  # ``serve.wait`` seconds
+        self.ticks = 0           # ``serve_once`` calls ...
+        self.empty_ticks = 0     # ... that served nothing
 
 
 class TconvServer:
@@ -147,6 +180,7 @@ class TconvServer:
         self._inflight: List[Tuple[BucketSpec, Request]] = []
         self._drain_crashes = 0
         self._drain_restarts = 0
+        self._loop_stats = _LoopStats()
         self._rng = np.random.default_rng(self.config.seed)  # backoff jitter
 
     # -- admission ----------------------------------------------------------
@@ -184,36 +218,38 @@ class TconvServer:
         breaker is open; ``requests``/``shed`` counters stay consistent
         (``requests == completed + failed + pending``).
         """
-        arr = np.asarray(inputs, np.float32)
-        try:
-            spec = self.bucket_for(model, arr.shape, precision)
-        except AdmissionError:
+        with phase("serve.submit") as span:
+            arr = np.asarray(inputs, np.float32)
+            try:
+                spec = self.bucket_for(model, arr.shape, precision)
+            except AdmissionError:
+                with self._lock:
+                    self._rejected += 1
+                raise
+            now = time.monotonic()
             with self._lock:
-                self._rejected += 1
-            raise
-        now = time.monotonic()
-        with self._lock:
-            stats = self._stats[spec.key]
-            breaker = self._breakers[spec.key]
-            if not breaker.allow(now):
-                stats.shed += 1
-                raise CircuitOpenError(
-                    f"bucket {spec.key} breaker is {breaker.state} "
-                    f"(after {breaker.consecutive_failures} consecutive "
-                    f"batch failures); shedding")
-        if deadline_s is None:
-            deadline_s = self.config.default_deadline_s
-        deadline = None if deadline_s is None else now + float(deadline_s)
-        req = Request(next(self._rid), model, arr, precision, now,
-                      deadline=deadline)
-        try:
-            self._batcher.put(spec, req)
-        except ShedError:
+                stats = self._stats[spec.key]
+                breaker = self._breakers[spec.key]
+                if not breaker.allow(now):
+                    stats.shed += 1
+                    raise CircuitOpenError(
+                        f"bucket {spec.key} breaker is {breaker.state} "
+                        f"(after {breaker.consecutive_failures} consecutive "
+                        f"batch failures); shedding")
+            if deadline_s is None:
+                deadline_s = self.config.default_deadline_s
+            deadline = None if deadline_s is None else now + float(deadline_s)
+            req = Request(next(self._rid), model, arr, precision, now,
+                          deadline=deadline)
+            span.set_metadata(rid=req.rid)
+            try:
+                self._batcher.put(spec, req)
+            except ShedError:
+                with self._lock:
+                    stats.shed += 1
+                raise
             with self._lock:
-                stats.shed += 1
-            raise
-        with self._lock:
-            stats.requests += 1
+                stats.requests += 1
         self._wake.set()
         return req
 
@@ -239,48 +275,53 @@ class TconvServer:
             self._stats[spec.key].failed += n
 
     def _run_batch(self, spec: BucketSpec, reqs, reason: str, now: float,
-                   batch_index: int) -> None:
+                   batch_index: int, due_wait: float) -> None:
         runner = self.runners[spec.key.model]
         target = spec.target_batch
         precision = spec.key.precision
         stats = self._stats[spec.key]
         breaker = self._breakers[spec.key]
         hit = runner.has_compiled(batch=target, precision=precision)
-        xs = np.zeros((target,) + spec.key.shape, np.float32)
-        for i, r in enumerate(reqs):
-            xs[i] = r.inputs
+        with phase("serve.pad", stats, batch=batch_index, n=len(reqs)):
+            xs = np.zeros((target,) + spec.key.shape, np.float32)
+            for i, r in enumerate(reqs):
+                xs[i] = r.inputs
         try:
             out, rung, retries = resilience.run_ladder(
                 self._ladder_for(spec.key.model), xs,
                 bucket=str(spec.key), batch=target, precision=precision,
                 batch_index=batch_index, config=self.config,
-                injector=self.injector, rng=self._rng)
+                injector=self.injector, rng=self._rng, stats=stats)
         except Exception as err:  # noqa: BLE001 — fulfil, don't wedge
-            self._fail_requests(spec, reqs, err)
-            with self._lock:
-                stats.batches += 1
-                breaker.record_failure(time.monotonic())
+            with phase("serve.fulfil", stats, batch=batch_index):
+                self._fail_requests(spec, reqs, err)
+                with self._lock:
+                    stats.batches += 1
+                    stats.due_wait_s += due_wait
+                    breaker.record_failure(time.monotonic())
             return
-        t_done = time.monotonic()
-        for i, r in enumerate(reqs):
-            r.set_result(out[i], t_done)
-        waits = [max(now - r.t_enqueue, 0.0) for r in reqs]
-        with self._lock:
-            stats.completed += len(reqs)
-            stats.batches += 1
-            stats.compile_hits += int(hit)
-            stats.fill_sum += len(reqs) / target
-            stats.wait_sum += sum(waits)
-            stats.wait_max = max(stats.wait_max, max(waits))
-            stats.retries += retries
-            stats.rungs[rung] += 1
-            if rung != RUNG_TUNED:
-                stats.degraded += 1
-            if reason == FLUSH_FULL:
-                stats.flush_full += 1
-            else:
-                stats.flush_deadline += 1
-            breaker.record_success()
+        with phase("serve.fulfil", stats, batch=batch_index):
+            t_done = time.monotonic()
+            for i, r in enumerate(reqs):
+                r.set_result(out[i], t_done)
+            waits = [max(now - r.t_enqueue, 0.0) for r in reqs]
+            with self._lock:
+                stats.completed += len(reqs)
+                stats.batches += 1
+                stats.due_wait_s += due_wait
+                stats.compile_hits += int(hit)
+                stats.fill_sum += len(reqs) / target
+                stats.wait_sum += sum(waits)
+                stats.wait_max = max(stats.wait_max, max(waits))
+                stats.retries += retries
+                stats.rungs[rung] += 1
+                if rung != RUNG_TUNED:
+                    stats.degraded += 1
+                if reason == FLUSH_FULL:
+                    stats.flush_full += 1
+                else:
+                    stats.flush_deadline += 1
+                breaker.record_success()
 
     def _expire(self, now: float) -> int:
         """Fail every queued request whose deadline has passed."""
@@ -308,18 +349,32 @@ class TconvServer:
         crash) leaves requests registered for :meth:`_fail_inflight`, so a
         crashed drain iteration never wedges its callers.
         """
-        now = time.monotonic() if now is None else now
-        served = self._expire(now)
-        for spec, reqs, reason in self._batcher.ready(now, force=force):
+        t_tick = time.monotonic()
+        now = t_tick if now is None else now
+        loop = self._loop_stats
+        with phase("serve.form", loop):
+            served = self._expire(now)
+            batches = self._batcher.ready(now, force=force)
+        for spec, reqs, reason in batches:
             batch_index = next(self._batch_seq)
+            for r in reqs:
+                r.batch = batch_index
             with self._lock:
                 self._inflight.extend((spec, r) for r in reqs)
             if self.injector is not None:
                 self.injector.maybe_crash(batch_index)
-            self._run_batch(spec, reqs, reason, now, batch_index)
+            # On the tick's clock (``now`` may be injected), the batch
+            # starts ``now`` plus the time this tick has taken so far.
+            started = now + time.monotonic() - t_tick
+            due_wait = max(started - self._batcher.due(reqs, reason, now),
+                           0.0)
+            self._run_batch(spec, reqs, reason, now, batch_index, due_wait)
             with self._lock:
                 self._inflight.clear()
             served += len(reqs)
+        loop.ticks += 1
+        if not served:
+            loop.empty_ticks += 1
         return served
 
     def _fail_inflight(self, err: BaseException) -> None:
@@ -411,7 +466,8 @@ class TconvServer:
             nd = self._batcher.next_deadline()
             wait = (self.max_wait_s if nd is None
                     else max(nd - time.monotonic(), 0.0))
-            self._wake.wait(min(wait, 0.05))
+            with phase("serve.wait", self._loop_stats):
+                self._wake.wait(min(wait, 0.05))
             self._wake.clear()
 
     def __enter__(self) -> "TconvServer":
@@ -429,7 +485,11 @@ class TconvServer:
                                       batches=batches)
 
     def stats(self) -> dict:
-        """Point-in-time snapshot of every bucket's counters."""
+        """Point-in-time snapshot of every bucket's counters, and the drain
+        loop's.  Counts and ``*_s`` seconds are cumulative since the server
+        was built, so a window's share is the difference of two snapshots;
+        the ratios and means are over the same span."""
+        loop = self._loop_stats
         with self._lock:
             by_key = {spec.key: spec for spec in self._buckets.values()}
             buckets = {str(key): self._stats[key].snapshot(
@@ -438,7 +498,9 @@ class TconvServer:
             out = {"buckets": buckets, "rejected": self._rejected,
                    "pending": self._batcher.pending(),
                    "drain_crashes": self._drain_crashes,
-                   "drain_restarts": self._drain_restarts}
+                   "drain_restarts": self._drain_restarts,
+                   "form_s": loop.form_s, "drain_wait_s": loop.drain_wait_s,
+                   "ticks": loop.ticks, "empty_ticks": loop.empty_ticks}
         if self.injector is not None:
             out["fault_injection"] = self.injector.stats()
         return out

@@ -185,6 +185,26 @@ def test_ladder_persistent_transient_descends_after_one_retry():
     assert r.tuned_calls == 2                    # attempt + one retry only
 
 
+def test_ladder_knows_which_rungs_have_compiled():
+    """``compiled`` (the ``serve.dispatch`` span's id): the runner's own
+    record for its rungs, a rung that has run for the others."""
+    r = FakeRunner()
+    r.fail_tuned = ValueError("deterministic")
+    ladder = DegradationLadder(r)
+    kw = dict(batch=2, precision="f32")
+    assert not ladder.compiled(RUNG_HEURISTIC, **kw)
+    ladder.fn(RUNG_HEURISTIC, **kw)(np.zeros((2, 4), np.float32))
+    assert ladder.compiled(RUNG_HEURISTIC, **kw)
+    assert not ladder.compiled(RUNG_LAX, **kw)
+    assert not ladder.compiled(RUNG_TUNED, **kw)  # FakeRunner never has
+    # A call that raised compiled nothing the next call can reuse.
+    r.fail_heuristic = True
+    failing = DegradationLadder(r)
+    with pytest.raises(RuntimeError, match="policy forward broken"):
+        failing.fn(RUNG_HEURISTIC, **kw)(np.zeros((2, 4), np.float32))
+    assert not failing.compiled(RUNG_HEURISTIC, **kw)
+
+
 def test_ladder_falls_to_lax_bottom():
     r = FakeRunner()
     r.fail_tuned = ValueError("broken")

@@ -53,21 +53,11 @@ from repro.core import model_fit
 #: over a handful of problems only partly damps that.
 DEFAULT_NOISE_BAND = 0.5
 
-#: Sections stripped from both docs before either leg runs.  The
-#: ``serve_chaos`` rows measure fault-*injected* degraded-mode serving
-#: (retries, ladder descents, shed bursts — ``benchmarks/bench_serve_tconv
-#: .run_chaos``): their latencies are artifacts of the injected faults,
-#: so banding on them would gate kernel PRs on chaos-harness noise.
-IGNORED_SECTIONS = ("serve_chaos",)
-
-
 def load_doc(path: str) -> dict:
     try:
         doc = json.loads(Path(path).read_text())
     except (OSError, ValueError) as e:
         raise SystemExit(f"bench_gate: cannot read {path}: {e}")
-    for section in IGNORED_SECTIONS:
-        doc.pop(section, None)
     return doc
 
 
