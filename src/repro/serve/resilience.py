@@ -428,6 +428,41 @@ class FaultInjector:
 
 
 # ---------------------------------------------------------------------------
+# Lane-dense transfer of the batch to the device.
+# ---------------------------------------------------------------------------
+
+#: Lanes of a TPU tile; a float32 tile is (8, 128).
+LANES = 128
+
+
+def lane_dense(xs) -> bool:
+    """Whether the host batch ``xs`` crosses to the device as a
+    ``(-1, LANES)`` slab.
+
+    The device tiles an array over its last two dims and keeps a small
+    minor dim out of the lanes: an NHWC batch with ``C = 3`` lies as
+    N, C, H, W on the device, and the runtime transposes it into that
+    order on the host, tile by tile, on the way in.  A float32
+    ``(R, 128)`` array with ``R % 8 == 0`` is tiled byte for byte as
+    row-major: it crosses as a plain copy, and the transpose into the
+    batch's own shape runs on the device instead.  The rule: a float32
+    numpy array of a whole number of ``(8, 128)`` tiles whose last dim
+    is not already a multiple of 128.  (The copy out needs none of this:
+    the device undoes its tiling on the way out, at the same cost for
+    either shape.)
+    """
+    return (isinstance(xs, np.ndarray) and xs.dtype == np.float32
+            and xs.size > 0 and xs.size % (8 * LANES) == 0
+            and xs.shape[-1] % LANES != 0)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _relayout(a, shape):
+    """``a.reshape(shape)`` on the device; compiled once per shape."""
+    return a.reshape(shape)
+
+
+# ---------------------------------------------------------------------------
 # Ladder execution (called by the server with the batch already padded).
 # ---------------------------------------------------------------------------
 
@@ -448,11 +483,18 @@ def run_ladder(ladder: DegradationLadder, xs, *, bucket: str, batch: int,
     feeds the breaker.  The copy in, each call and the copy out are the
     ``serve.put``, ``serve.dispatch`` and ``serve.fetch`` phases
     (``serve/telemetry.py``), counted in ``stats`` when it is given.
+    The copy in crosses as a lane-dense slab when the batch qualifies
+    (:func:`lane_dense`; ``slab=`` on the span, ``slab_in`` in
+    ``stats``); every rung still receives the batch in its own shape.
     """
     retries = 0
     last: Optional[BaseException] = None
-    with phase("serve.put", stats, batch=batch_index):
-        x_dev = jnp.asarray(xs)
+    slab_in = lane_dense(xs)
+    with phase("serve.put", stats, batch=batch_index, slab=slab_in):
+        x_dev = (_relayout(jnp.asarray(xs.reshape(-1, LANES)), xs.shape)
+                 if slab_in else jnp.asarray(xs))
+    if stats is not None:
+        stats.slab_in += slab_in
     for rung in ladder.rungs(precision):
         try:
             fn = ladder.fn(rung, batch=batch, precision=precision)

@@ -72,8 +72,8 @@ class _BucketStats:
     __slots__ = ("requests", "completed", "failed", "batches", "flush_full",
                  "flush_deadline", "fill_sum", "wait_sum", "wait_max",
                  "pad_s", "put_s", "dispatch_s", "fetch_s", "fulfil_s",
-                 "due_wait_s", "compile_hits", "shed", "deadline_expired",
-                 "retries", "degraded", "rungs")
+                 "due_wait_s", "slab_in", "compile_hits", "shed",
+                 "deadline_expired", "retries", "degraded", "rungs")
 
     def __init__(self):
         self.requests = 0       # successfully enqueued (excludes sheds)
@@ -95,6 +95,9 @@ class _BucketStats:
         self.fetch_s = 0.0
         self.fulfil_s = 0.0
         self.due_wait_s = 0.0
+        # Batches whose input crossed to the device as a lane-dense slab
+        # (``resilience.lane_dense``).
+        self.slab_in = 0
         self.compile_hits = 0
         self.shed = 0           # rejected at admission for load (not queued)
         self.deadline_expired = 0
@@ -126,6 +129,7 @@ class _BucketStats:
             "fetch_s": self.fetch_s,
             "fulfil_s": self.fulfil_s,
             "due_wait_s": self.due_wait_s,
+            "slab_in": self.slab_in,
             "compile_hits": self.compile_hits,
             "shed": self.shed,
             "deadline_expired": self.deadline_expired,

@@ -26,7 +26,7 @@ COUNTERS = {
     "serve.wait": "drain_wait_s",    # drain thread blocked, no batch due
     "serve.form": "form_s",          # expiries and ``Batcher.ready``
     "serve.pad": "pad_s",            # zero-padded host batch
-    "serve.put": "put_s",            # ``jnp.asarray``: host -> device started
+    "serve.put": "put_s",            # host -> device started (``slab``)
     "serve.dispatch": "dispatch_s",  # the call that enqueues the program
     "serve.fetch": "fetch_s",        # ``np.asarray``: device done, D2H
     "serve.fulfil": "fulfil_s",      # results handed out, counters updated

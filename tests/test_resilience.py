@@ -256,6 +256,140 @@ def test_ladder_memoizes_rung_fns():
 
 
 # ---------------------------------------------------------------------------
+# Lane-dense transfer: the batch crosses to the device as a (-1, 128)
+# slab when it qualifies, and every rung still sees the batch's own shape.
+# ---------------------------------------------------------------------------
+
+
+class _ShapedSpec:
+    """Heuristic/lax rungs: a marker times ones, recording the shape each
+    trace received; a failing policy raises as ``_FakeSpec``'s does."""
+
+    def __init__(self):
+        self.seen = []
+
+    def forward(self, params, x, *, options=None, policy=None):
+        self.seen.append(tuple(x.shape))
+        if getattr(policy, "fail", False):
+            raise RuntimeError("policy forward broken")
+        return jnp.ones_like(x) * getattr(policy, "marker", MARK_LAX)
+
+
+class ShapedRunner(FakeRunner):
+    """FakeRunner at any per-request shape; its tuned rung computes
+    ``out(x)`` and records the type and shape of each ``x`` it gets."""
+
+    def __init__(self, shape, out=lambda x: x * 2.0 + 1.0):
+        super().__init__()
+        self.shape, self.out, self.seen = tuple(shape), out, []
+        self.spec = _ShapedSpec()
+
+    def input_shape(self):
+        return self.shape
+
+    def jitted(self, *, batch, precision="f32"):
+        def fn(x):
+            self.seen.append((type(x), tuple(x.shape)))
+            self.tuned_calls += 1
+            if self.fail_tuned is not None and (
+                    self.fail_tuned_times == 0
+                    or self.tuned_calls <= self.fail_tuned_times):
+                raise self.fail_tuned
+            return self.out(x)
+
+        return fn
+
+
+def _batch(shape):
+    return np.random.default_rng(0).standard_normal(shape).astype(np.float32)
+
+
+def _run_shaped(runner, xs, monkeypatch):
+    """run_ladder with a bucket's counters; also returns each span's ids."""
+    from repro.serve import server as server_mod, telemetry
+
+    ids = {}
+
+    def recording(name, stats=None, **kw):
+        ids[name] = kw
+        return telemetry.phase(name, stats, **kw)
+
+    monkeypatch.setattr(resilience, "phase", recording)
+    stats = server_mod._BucketStats()
+    out, rung, retries = run_ladder(
+        DegradationLadder(runner), xs, bucket="shaped", batch=xs.shape[0],
+        precision="f32", batch_index=1, config=ResilienceConfig(),
+        rng=np.random.default_rng(0), sleep=NOSLEEP, stats=stats)
+    return out, rung, retries, stats, ids
+
+
+@pytest.mark.parametrize("shape, dtype, want", [
+    ((2, 16, 16, 4), np.float32, True),     # 2,048 elements, C = 4
+    ((8, 64, 64, 3), np.float32, True),     # DCGAN's output
+    ((8, 100), np.float32, False),          # DCGAN's z: 800 elements
+    ((2, 8, 100), np.float32, False),       # 1,600: not whole tiles
+    ((2, 8, 128), np.float32, False),       # minor dim already lane-wide
+    ((2, 16, 16, 4), np.int32, False),      # not float32
+    ((0, 16, 16, 4), np.float32, False),    # empty
+])
+def test_lane_dense_rule(shape, dtype, want):
+    xs = np.zeros(shape, dtype)
+    assert resilience.lane_dense(xs) == want
+    assert not resilience.lane_dense(jnp.asarray(xs))   # not a host batch
+
+
+@pytest.mark.parametrize("shape, out, slab", [
+    ((2, 16, 16, 4), lambda x: x * 2.0 + 1.0, True),
+    ((2, 16, 16, 4), lambda x: np.asarray(x) * 2.0, True),   # numpy out
+    ((2, 16, 16, 4), lambda x: (x * 4.0).astype(jnp.int32), True),
+    ((2, 8, 100), lambda x: x - 1.0, False),       # not whole tiles
+    ((2, 8, 128), lambda x: x - 1.0, False),       # lane-wide already
+    # DCGAN's shape of path: a z in, an image out.
+    ((2, 100), lambda x: jnp.tile(x[:, :48], (1, 32)).reshape(2, 16, 16, 6),
+     False),
+], ids=["nhwc", "numpy-out", "int-out", "odd-size", "lane-wide", "z-to-image"])
+def test_run_ladder_slab_path_is_bit_identical(shape, out, slab,
+                                               monkeypatch):
+    """Output as the plain path gives it, byte for byte, in the rung's
+    shape and dtype; the rung got the batch's shape on the device; the
+    counter and the span's ``slab=`` say whether the input was a slab."""
+    xs = _batch(shape)
+    r = ShapedRunner(shape[1:], out)
+    got, rung, _, stats, ids = _run_shaped(r, xs, monkeypatch)
+    want = np.asarray(out(jnp.asarray(xs)))
+    assert rung == RUNG_TUNED
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert got.tobytes() == want.tobytes()
+    assert r.seen == [(type(jnp.asarray(xs)), shape)]
+    assert stats.slab_in == int(slab)
+    assert ids["serve.put"]["slab"] == slab
+    assert "slab" not in ids["serve.fetch"]
+
+
+@pytest.mark.parametrize("fail, heuristic_broken, want_rung, want_calls", [
+    (TransientFault("blip"), False, RUNG_TUNED, 2),   # retried in place
+    (ValueError("deterministic"), False, RUNG_HEURISTIC, 1),
+    (ValueError("deterministic"), True, RUNG_LAX, 1),
+])
+def test_rung_failure_on_slab_path_walks_the_ladder(
+        fail, heuristic_broken, want_rung, want_calls, monkeypatch):
+    """A batch that crossed as a slab descends the ladder as before, and
+    every rung tried receives the batch in its own shape."""
+    shape = (2, 16, 16, 4)
+    r = ShapedRunner(shape[1:], lambda x: x * 0.0 + MARK_TUNED)
+    r.fail_tuned, r.fail_tuned_times = fail, 1 if want_calls == 2 else 0
+    r.fail_heuristic = heuristic_broken
+    got, rung, _, stats, _ = _run_shaped(r, _batch(shape), monkeypatch)
+    marks = {RUNG_TUNED: MARK_TUNED, RUNG_HEURISTIC: MARK_HEURISTIC,
+             RUNG_LAX: MARK_LAX}
+    assert rung == want_rung and r.tuned_calls == want_calls
+    np.testing.assert_array_equal(got, np.full(shape, marks[want_rung]))
+    assert {s for _, s in r.seen} == {shape}
+    assert set(r.spec.seen) <= {shape}
+    assert stats.slab_in == 1
+
+
+# ---------------------------------------------------------------------------
 # Circuit breaker state machine (injected clock).
 # ---------------------------------------------------------------------------
 
@@ -458,6 +592,31 @@ def test_server_records_rungs_and_degraded():
     assert b["degraded"] == 2 and b["retries"] == 2
     assert b["completed"] == 4 and b["failed"] == 0
     assert srv.stats()["fault_injection"]["fail"] == 4  # 2 per bad batch
+
+
+@pytest.mark.parametrize("shape, exhausted, want", [
+    ((16, 16, 4), False, 3),    # each batch crosses as a slab
+    ((4,), False, 0),           # too small: the plain copy
+    ((16, 16, 4), True, 3),     # crossed, then every rung failed
+])
+def test_server_counts_slab_batches(shape, exhausted, want):
+    """``slab_in`` counts exactly the batches whose input crossed as a
+    slab: three batches of 2 (two full, one forced)."""
+    r = ShapedRunner(shape)
+    if exhausted:
+        r.fail_tuned, r.fail_heuristic = ValueError("broken"), True
+        r.spec.forward = lambda params, x, options=None, policy=None: (
+            (_ for _ in ()).throw(RuntimeError("lax broken")))
+    _, srv = _server(r)
+    reqs = [srv.submit("fake", np.ones(shape, np.float32)) for _ in range(5)]
+    assert srv.serve_once(force=True) == 5
+    [b] = srv.stats()["buckets"].values()
+    assert b["batches"] == 3 and b["slab_in"] == want
+    assert b["failed"] == (5 if exhausted else 0)
+    if not exhausted:
+        for q in reqs:
+            np.testing.assert_array_equal(q.result(timeout=0),
+                                          np.full(shape, 3.0))
 
 
 def test_server_straggler_composition_counts_stalls():
