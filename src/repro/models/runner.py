@@ -38,10 +38,12 @@ synthetic sample records per-layer symmetric absmax scales for the
 input, weight, and pre-activation accumulator; scales are python floats,
 so they are static under jit and the requant epilogue never retraces.
 
-Serving caveat: the models compute batch statistics inline (BN folding
-is a deployment-time transform the repo doesn't model), so a request's
-output depends on its co-batched neighbors.  The serving layer
+Serving caveat: the paper's four models compute batch statistics inline
+(BN folding is a deployment-time transform the repo doesn't model), so a
+request's output depends on its co-batched neighbors.  The serving layer
 (`repro/serve/`) documents and tests against the batched forward.
+StyleGAN2 (``models/stylegan2.py``) has no batch statistics: each row is
+the request served alone.
 """
 
 from __future__ import annotations
@@ -55,7 +57,7 @@ import jax.numpy as jnp
 
 from repro.core import epilogue as epi
 from repro.kernels import ops
-from repro.models import gan
+from repro.models import gan, stylegan2
 
 DEFAULT_METHOD = ops.DEFAULT_METHOD
 PRECISIONS: Tuple[str, ...] = ("f32", "int8")
@@ -363,7 +365,8 @@ def make_runner(name: str, *, params=None, key=None, init_kw=None,
 
 
 # ---------------------------------------------------------------------------
-# Registrations — the four generator families of the paper's evaluation.
+# Registrations — the four generator families of the paper's evaluation,
+# and StyleGAN2 (modulated convolutions, VALID up-convolutions).
 # ---------------------------------------------------------------------------
 
 
@@ -382,6 +385,10 @@ def _fsrcnn_forward(params, img, options, *, policy):
 
 def _styletransfer_forward(params, img, options, *, policy):
     return gan.styletransfer(params, img, policy=policy)
+
+
+def _stylegan2_forward(params, z, options, *, policy):
+    return stylegan2.generator(params, z, policy=policy)
 
 
 register_spec(RunnerSpec(
@@ -421,4 +428,12 @@ register_spec(RunnerSpec(
     input_shape=lambda p, opt: (opt["input_hw"], opt["input_hw"],
                                 p["c1"].shape[2]),
     defaults={"input_hw": 32},
+))
+
+register_spec(RunnerSpec(
+    name="stylegan2",
+    init=stylegan2.init,
+    forward=_stylegan2_forward,
+    problems=lambda p, opt: stylegan2.tconv_problems(p),
+    input_shape=lambda p, opt: (p["map0.w"].shape[0],),
 ))

@@ -329,3 +329,16 @@ def test_run_registered_matches_dispatch():
                                     epilogue=ep8))
     want = np.asarray(tconv_int8(xq, wq, bq, 0.004, stride=S, method="tdc"))
     assert (got == want).all()
+
+
+@pytest.mark.parametrize("i", [4, 8])
+def test_valid_3x3_stride2_dispatch_matches_lax_at_odd_outputs(i):
+    """StyleGAN2's up-convolution: VALID, 3x3, stride 2, through the
+    default dispatch (the MM2IM kernel) gives ``2i+1`` rows and columns,
+    equal to 'lax'."""
+    x = RNG.standard_normal((2, i, i, 16)).astype(np.float32)
+    w = RNG.standard_normal((3, 3, 8, 16)).astype(np.float32)
+    got = np.asarray(tconv(x, w, stride=2, padding="VALID"))
+    want = np.asarray(tconv(x, w, stride=2, padding="VALID", method="lax"))
+    assert got.shape == (2, 2 * i + 1, 2 * i + 1, 8)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)

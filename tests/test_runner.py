@@ -17,11 +17,11 @@ import jax.numpy as jnp
 from repro.core.maps import TConvProblem
 from repro.kernels import ops
 from repro.kernels.registry import Plan
-from repro.models import gan
+from repro.models import gan, stylegan2
 from repro.models.runner import (DEFAULT_METHOD, GeneratorRunner, make_runner,
                                  get_spec, runner_names)
 
-MODELS = ("dcgan", "pix2pix", "fsrcnn", "styletransfer")
+MODELS = ("dcgan", "pix2pix", "fsrcnn", "styletransfer", "stylegan2")
 
 # CPU-sized geometry per family (same knobs the serve smoke CLI uses).
 TINY = {
@@ -29,6 +29,8 @@ TINY = {
     "pix2pix": dict(init_kw={"depth": 4, "scale_down": 16}),
     "fsrcnn": dict(init_kw={"d": 8, "s": 4, "m": 1}, input_hw=8),
     "styletransfer": dict(init_kw={"base": 8, "n_res": 1}, input_hw=16),
+    "stylegan2": dict(init_kw={"resolution": 32, "z_dim": 32, "w_dim": 32,
+                               "mapping_layers": 2, "fmap_max": 32}),
 }
 
 
@@ -68,6 +70,8 @@ def _legacy_forward(name, params, x):
                                      method=DEFAULT_METHOD)
     if name == "fsrcnn":
         return gan.fsrcnn(params, x, method=DEFAULT_METHOD)
+    if name == "stylegan2":
+        return stylegan2.generator(params, x, method=DEFAULT_METHOD)
     return gan.styletransfer(params, x, method=DEFAULT_METHOD)
 
 
@@ -95,7 +99,8 @@ class _RecordingPolicy:
     def tconv(self, x, w, bias=None, *, name, stride, padding="SAME",
               activation="none"):
         self.layers[name] = TConvProblem(x.shape[1], x.shape[2], x.shape[3],
-                                         w.shape[0], w.shape[2], stride)
+                                         w.shape[0], w.shape[2], stride,
+                                         padding)
         return ops.tconv(x, w, bias, stride=stride, padding=padding,
                          method="lax", activation=activation)
 

@@ -8,6 +8,9 @@ cannot see what these catch: blocks that break the (8, 128) tiling,
 lowerings Mosaic lacks, and kernels that overrun their scoped VMEM
 (``VMEM_LIMIT``, which Mosaic enforces at compile time).
 
+StyleGAN2-256's six VALID 3x3 stride-2 up-convolutions compile too, at
+the served batch of 8 in f32.
+
 All compiles happen in this one file, inside tests: the TPU library is
 loaded by one process at a time, so describing the topology at import
 time would break the other test workers.
@@ -36,6 +39,9 @@ CASES = ([(name, batch) for name in DCGAN for batch in (1, 8)]
 # the whole 256x256 input stays resident (about 36 MB in f32, modeled
 # twice for double buffering).  Mosaic still compiles it under VMEM_LIMIT.
 OVER_BUDGET = {("StyleTransfer_3", "f32")}
+# StyleGAN2-256 (config-f): (input size, Ic, Oc) of each up-convolution.
+STYLEGAN2_UP = [(4, 512, 512), (8, 512, 512), (16, 512, 512), (32, 512, 512),
+                (64, 512, 256), (128, 256, 128)]
 
 
 @pytest.fixture(scope="module")
@@ -115,3 +121,23 @@ def test_int8_compiles_under_highest_matmul_precision(one_chip, tconv):
             x, w, stride=r.stride, out_scale=0.01, interpret=False)).lower(
                 x, w).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+@pytest.mark.parametrize("ihw,ic,oc", STYLEGAN2_UP)
+def test_stylegan2_valid_up_convolution_compiles_for_v5e(one_chip, ihw, ic,
+                                                         oc):
+    x = jax.ShapeDtypeStruct((8, ihw, ihw, ic), jnp.float32,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((3, 3, oc, ic), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda x, w: mm2im_tconv(
+        x, w, stride=2, padding="VALID", interpret=False)).lower(
+            x, w).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    block_oh, block_oc = plan_blocks(ihw, ihw, ic, 3, oc, 2, "VALID",
+                                     batch=8)
+    assert mm2im_vmem_bytes(ihw, ihw, ic, 3, oc, 2, "VALID",
+                            block_oh=block_oh, block_oc=block_oc,
+                            batch=8) <= VMEM_BUDGET
+    (out,) = jax.tree.leaves(compiled.out_info)
+    assert out.shape == (8, 2 * ihw + 1, 2 * ihw + 1, oc)
